@@ -95,7 +95,10 @@ class PipelineConfig:
       ping-pong slot. This is the only backend that scales *GIL-holding*
       Python emulators (ALE-style wrappers, pure-Python simulators), whose
       env stepping serializes the thread plane no matter how many replicas
-      run; it implies the host rollout plane.
+      run; it implies the host rollout plane. The workers act on the host
+      CPU: each pins its JAX to the CPU platform before any array exists,
+      so the parent learner is the only process that touches the
+      accelerator (a chip belongs to one process at a time).
 
     ``mesh_shape`` scales the device plane across accelerators:
     ``mesh_shape=D > 1`` builds a 1-axis ``("data",)`` ``jax.sharding.Mesh``

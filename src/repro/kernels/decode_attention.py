@@ -3,6 +3,10 @@
 Flash-decoding adapted to TPU: grid (B, Hkv, Sk/block_k) with the KV-block
 axis innermost, streaming the cache through VMEM once while fp32 scratch
 (m, l, acc) carries the online-softmax state for the G grouped query heads.
+The kernel reads the cache head-major, (B, Hkv, S, D), so each K/V block is
+a (block_k, D) tile that meets the TPU's (8, 128) block rule; the wrapper
+transposes the (B, S, Hkv, D) serving layout into it (a cache stored
+head-major would skip that pass).
 The valid-length bound (``pos``) is a scalar-prefetch operand so masked
 tail blocks are skipped entirely (``pl.when``), making decode cost
 proportional to the *filled* cache, not its capacity.
@@ -40,8 +44,8 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     @pl.when(k_start <= pos)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (bk, Dv)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)  # (bk, Dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (G, bk)
@@ -71,7 +75,7 @@ def decode_attention_pallas(
     *,
     scale=None,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, H, D = q.shape
     _, S, Hkv, Dv = v_cache.shape
@@ -81,6 +85,8 @@ def decode_attention_pallas(
     pad_k = (-S) % block_k
     kk = jnp.pad(k_cache, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else k_cache
     vv = jnp.pad(v_cache, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else v_cache
+    kk = kk.transpose(0, 2, 1, 3)  # head-major (B, Hkv, S, D)
+    vv = vv.transpose(0, 2, 1, 3)
     nk = (S + pad_k) // block_k
     qg = q.reshape(B, Hkv, G, D)
     pos_arr = jnp.full((1,), pos, jnp.int32)
@@ -90,8 +96,8 @@ def decode_attention_pallas(
         grid=(B, Hkv, nk),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, ki, pos_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ki, pos_ref: (b, ki, h, 0)),
-            pl.BlockSpec((1, block_k, 1, Dv), lambda b, h, ki, pos_ref: (b, ki, h, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, pos_ref: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, ki, pos_ref: (b, h, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, Dv), lambda b, h, ki, pos_ref: (b, h, 0, 0)),
         scratch_shapes=[

@@ -90,7 +90,7 @@ def flash_attention_pallas(
     scale=None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape
     _, Sk, Hkv, Dv = v.shape

@@ -82,7 +82,7 @@ def mla_decode_attention_pallas(
     scale: float,
     *,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Returns latent-space attention output (B, H, R)."""
     B, H, R = q_lat.shape

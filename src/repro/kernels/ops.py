@@ -1,15 +1,16 @@
 """Jit'd dispatch wrappers around the Pallas kernels.
 
-``backend="pallas"`` runs the TPU kernels (interpret mode on CPU — the
-container target), ``backend="ref"`` the pure-jnp oracles. Model code and
-benchmarks call these; tests sweep both and assert equality.
+``backend="pallas"`` runs the TPU kernels, ``backend="ref"`` the pure-jnp
+oracles. Model code and benchmarks call these; tests sweep both and assert
+equality. This module is the one place that decides interpret mode: at
+trace time, kernels run interpreted when the default backend is the CPU
+and compiled everywhere else. Importing it touches no backend.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 from repro.kernels.decode_attention import decode_attention_pallas
@@ -19,14 +20,17 @@ from repro.kernels.nstep_returns import nstep_returns_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.kernels.vtrace import vtrace_returns_pallas
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("gamma", "backend"))
 def nstep_returns(rewards, dones, bootstrap, gamma: float, backend: str = "pallas"):
     if backend == "ref":
         return _ref.nstep_returns_ref(rewards, dones, bootstrap, gamma)
-    return nstep_returns_pallas(rewards, dones, bootstrap, gamma, interpret=_INTERPRET)
+    return nstep_returns_pallas(rewards, dones, bootstrap, gamma,
+                                interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("gamma", "rho_bar", "c_bar", "backend"))
@@ -37,7 +41,7 @@ def vtrace_returns(rewards, dones, values, bootstrap, rho, gamma: float,
         return _ref.vtrace_returns_ref(rewards, dones, values, bootstrap, rho,
                                        gamma, rho_bar, c_bar)
     return vtrace_returns_pallas(rewards, dones, values, bootstrap, rho, gamma,
-                                 rho_bar, c_bar, interpret=_INTERPRET)
+                                 rho_bar, c_bar, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k", "backend"))
@@ -47,7 +51,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128, block_k=128,
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, block_q=block_q, block_k=block_k,
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )
 
 
@@ -56,7 +60,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, block_k=512, backend: str = "p
     if backend == "ref":
         return _ref.decode_attention_ref(q, k_cache, v_cache, pos)
     return decode_attention_pallas(
-        q, k_cache, v_cache, pos, block_k=block_k, interpret=_INTERPRET
+        q, k_cache, v_cache, pos, block_k=block_k, interpret=_interpret()
     )
 
 
@@ -68,7 +72,7 @@ def mla_decode_attention(q_lat, q_rope, c_cache, kr_cache, pos, scale: float,
                                              pos, scale)
     return mla_decode_attention_pallas(
         q_lat, q_rope, c_cache, kr_cache, pos, scale, block_k=block_k,
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )
 
 
@@ -78,4 +82,4 @@ def ssd_scan(x, dt, A_log, B_mat, C_mat, D_vec, *, chunk=128, backend: str = "pa
         y, _ = _ref.ssd_scan_ref(x, dt, A_log, B_mat, C_mat, D_vec)
         return y
     return ssd_scan_pallas(x, dt, A_log, B_mat, C_mat, D_vec, chunk=chunk,
-                           interpret=_INTERPRET)
+                           interpret=_interpret())

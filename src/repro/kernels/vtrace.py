@@ -9,12 +9,13 @@ corrections into the n-step recursion:
     v_t = V_t + A_t
     pg_adv_t = min(ρ̄, rho_t)·(r_t + γ_t·v_{t+1} - V_t)
 
-The kernel tiles the actor dimension into VMEM blocks (grid over E/block_e)
-and walks t_max backwards inside the block, producing both the value targets
-and the policy-gradient advantages in one HBM round-trip per tile.
+The kernel uses ``nstep_returns``' time-major tiling: ``(T, block_e)``
+blocks with actors on the lane axis (grid over E/block_e), walking t_max
+backwards one sublane row at a time, producing both the value targets and
+the policy-gradient advantages in one HBM round-trip per tile.
 
-VMEM budget: (7·block_e·T + 2·block_e) fp32 — block_e=256, T=4096 → 29 MB;
-use block_e=64 for long horizons.
+VMEM budget: (7·T + 8)·block_e fp32 — block_e=256, T=4096 → 29 MB;
+use block_e=128 for long horizons.
 """
 from __future__ import annotations
 
@@ -24,36 +25,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.nstep_returns import time_major_tiles
+
 
 def _kernel(r_ref, nd_ref, v_ref, vnext_ref, rho_ref, boot_ref,
             vs_ref, adv_ref, *, gamma: float, rho_bar: float, c_bar: float,
             T: int):
-    zero = jnp.zeros_like(boot_ref[...].astype(jnp.float32))  # A_T = 0
-    vs_next0 = boot_ref[...].astype(jnp.float32)  # v_T = V(s_{T+1})
-
     def body(i, carry):
-        acc, vs_next = carry  # A_{t+1}, v_{t+1}
+        acc, vs_next = carry  # (1, block_e) rows A_{t+1}, v_{t+1}
         t = T - 1 - i
-        r_t = pl.load(r_ref, (slice(None), pl.dslice(t, 1)))[:, 0]
-        nd_t = pl.load(nd_ref, (slice(None), pl.dslice(t, 1)))[:, 0]
-        v_t = pl.load(v_ref, (slice(None), pl.dslice(t, 1)))[:, 0]
-        vn_t = pl.load(vnext_ref, (slice(None), pl.dslice(t, 1)))[:, 0]
-        rho_t = pl.load(rho_ref, (slice(None), pl.dslice(t, 1)))[:, 0]
-        rho_t = rho_t.astype(jnp.float32)
-        disc = gamma * nd_t.astype(jnp.float32)
+        row = pl.ds(t, 1)
+        r_t, v_t = r_ref[row, :], v_ref[row, :]
+        rho_t = rho_ref[row, :]
+        disc = gamma * nd_ref[row, :]
         rc = jnp.minimum(rho_t, rho_bar)
         c = jnp.minimum(rho_t, c_bar)
-        delta = rc * (r_t.astype(jnp.float32) + disc * vn_t.astype(jnp.float32)
-                      - v_t.astype(jnp.float32))
+        delta = rc * (r_t + disc * vnext_ref[row, :] - v_t)
         acc = delta + disc * c * acc
-        vs_t = v_t.astype(jnp.float32) + acc
-        adv_t = rc * (r_t.astype(jnp.float32) + disc * vs_next
-                      - v_t.astype(jnp.float32))
-        pl.store(vs_ref, (slice(None), pl.dslice(t, 1)), vs_t[:, None])
-        pl.store(adv_ref, (slice(None), pl.dslice(t, 1)), adv_t[:, None])
+        vs_t = v_t + acc
+        vs_ref[row, :] = vs_t
+        adv_ref[row, :] = rc * (r_t + disc * vs_next - v_t)
         return acc, vs_t
 
-    jax.lax.fori_loop(0, T, body, (zero, vs_next0))
+    boot = boot_ref[...]  # v_T = V(s_{T+1})
+    jax.lax.fori_loop(0, T, body, (jnp.zeros_like(boot), boot))
 
 
 def vtrace_returns_pallas(
@@ -67,39 +62,25 @@ def vtrace_returns_pallas(
     c_bar: float = 1.0,
     *,
     block_e: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Returns ``(vs, pg_adv)``, both (E, T) fp32 — the Pallas twin of
     ``repro.core.returns.vtrace_returns``."""
     E, T = rewards.shape
-    block_e = min(block_e, E)
-    pad = (-E) % block_e
-    r = rewards.astype(jnp.float32)
     nd = 1.0 - dones.astype(jnp.float32)
     v = values.astype(jnp.float32)
     b = bootstrap.astype(jnp.float32)
-    w = rho.astype(jnp.float32)
     vn = jnp.concatenate([v[:, 1:], b[:, None]], axis=1)
-    if pad:
-        r = jnp.pad(r, ((0, pad), (0, 0)))
-        nd = jnp.pad(nd, ((0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, pad), (0, 0)))
-        vn = jnp.pad(vn, ((0, pad), (0, 0)))
-        w = jnp.pad(w, ((0, pad), (0, 0)))
-        b = jnp.pad(b, ((0, pad),))
-    grid = ((E + pad) // block_e,)
-    mat = pl.BlockSpec((block_e, T), lambda e: (e, 0))
+    tile, grid, mats, rows = time_major_tiles(
+        block_e, E, [rewards, nd, v, vn, rho], [b])
+    mat = pl.BlockSpec((T, tile), lambda e: (0, e))
     vs, adv = pl.pallas_call(
         functools.partial(_kernel, gamma=gamma, rho_bar=rho_bar, c_bar=c_bar,
                           T=T),
         grid=grid,
-        in_specs=[mat, mat, mat, mat, mat,
-                  pl.BlockSpec((block_e,), lambda e: (e,))],
+        in_specs=[mat] * 5 + [pl.BlockSpec((1, tile), lambda e: (0, e))],
         out_specs=(mat, mat),
-        out_shape=(
-            jax.ShapeDtypeStruct((E + pad, T), jnp.float32),
-            jax.ShapeDtypeStruct((E + pad, T), jnp.float32),
-        ),
+        out_shape=(jax.ShapeDtypeStruct(mats[0].shape, jnp.float32),) * 2,
         interpret=interpret,
-    )(r, nd, v, vn, w, b)
-    return vs[:E], adv[:E]
+    )(*mats, *rows)
+    return vs[:, :E].T, adv[:, :E].T

@@ -43,7 +43,12 @@ def make_rollout_mesh(n_devices: int = 0):
             "CPU, export XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n} before the first jax import"
         )
-    return jax.make_mesh((n,), ("data",), devices=devices[:n])
+    # Auto axes: the learner leaves partitioning (and the gradient
+    # all-reduce) to XLA's SPMD partitioner. JAX's default Explicit axes
+    # would put the sharding into every array's type and refuse the
+    # learner's (T, E) -> (T*E,) batch flatten over the sharded env axis.
+    return jax.make_mesh((n,), ("data",), devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 # Hardware constants for the roofline (TPU v5e)

@@ -37,7 +37,7 @@ from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.launch.steps import build_serve_step
 from repro.models import init_policy
 from repro.telemetry import Telemetry
-from repro.utils import get_logger
+from repro.utils import get_logger, use_compile_cache
 
 log = get_logger("serve")
 
@@ -173,6 +173,7 @@ def main(argv=None):
     ap.add_argument("--metrics-jsonl", default="",
                     help="append a JSONL metrics heartbeat here")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     hub = Telemetry()
     if args.metrics_jsonl:

@@ -82,7 +82,7 @@ from repro.envs import TokenEnv
 from repro.launch.steps import build_train_step
 from repro.models import init_policy
 from repro.optim import constant
-from repro.utils import get_logger
+from repro.utils import get_logger, use_compile_cache
 
 log = get_logger("train")
 
@@ -428,6 +428,7 @@ def main():
                     "and run only the remaining iterations (bitwise "
                     "continuation on the thread backend's FIFO planes)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.mode == "rl":
         run_rl(args)
     else:

@@ -567,6 +567,11 @@ class ActorThread(ActorBase):
         self._state_log: dict = {}
         self._state_lock = make_lock("actor.state")
 
+    @property
+    def key(self):
+        """This replica's RNG key as of its latest rollout."""
+        return self._key
+
     def consume_state(self, seq: int):
         """Pop (and prune up to) the resume state recorded after rollout
         ``seq``; ``None`` when snapshotting is off or seq predates it."""

@@ -425,6 +425,7 @@ class PipelinedRL:
         self._live_slot_state: Dict[int, tuple] = {}
         self._resume_slot_state: Optional[Dict[int, tuple]] = None
         self.supervisor = None  # the last run()'s ActorSupervisor (elastic)
+        self.actors: List = []  # the last run()'s actor replicas
 
     # -- queue plane ---------------------------------------------------------
     def _resolve_plane(self, plane: str) -> str:
@@ -881,7 +882,7 @@ class PipelinedRL:
                     # replica's in-flight set may be unrecoverable
                     a = ActorThread(
                         self._make_collect(dead.slot_index),
-                        queue, slot, dead._key, remaining,
+                        queue, slot, dead.key, remaining,
                         lockstep=cfg.lockstep, actor_id=new_id,
                         telemetry=hub, slot_index=dead.slot_index,
                         ledger=ledger, injector=injector,
@@ -899,8 +900,9 @@ class PipelinedRL:
             for a in actors:
                 sup.register(a)
         # kept on self (like .telemetry) so harnesses/tests can audit the
-        # run's fault episodes after run() returns
+        # run's fault episodes and its replicas after run() returns
         self.supervisor = sup
+        self.actors = actors
         # device plane: never sync the learner loop — metric scalars are
         # stashed and converted once at result(), so update i+1 dispatches
         # while update i still executes. Host plane: eager (the blocking
@@ -1152,7 +1154,7 @@ class PipelinedRL:
                 if last.final_key is not None:
                     self.key = jnp.asarray(last.final_key)
             else:
-                self.key = last._key
+                self.key = last.key
         per_actor_idle = [a.put_wait_s + a.wait_s for a in actors]
         # the end-of-run metrics drain pulls every stashed device scalar to
         # host in one batch — the device planes' one intended D2H sync

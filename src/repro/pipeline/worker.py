@@ -27,7 +27,9 @@ untouched)::
 Wire protocol (per worker, all ``mp.Queue``):
 
 * ``cmd_q``   parent→child: ``("run", quota, lockstep)`` | ``("stop",)``
-* ``ready_q`` child→parent: ``("rollout", set_idx, seq, version)`` …
+* ``ready_q`` child→parent: ``("ready", platform)`` once, after setup
+  (the JAX platform the child acts on — always ``"cpu"``), then per run
+  ``("rollout", set_idx, seq, version)`` …
   then ``("spans", SpanEmitter.ship())`` — the child's telemetry ring
   (collect / lease / shm.copy / staging-wait spans, recorded child-side),
   merged parent-side under per-process trace track ``actor_id + 1`` —
@@ -39,6 +41,11 @@ Wire protocol (per worker, all ``mp.Queue``):
   ``HostStagingRing`` lease. The parent seeds ``queue_depth + 2`` indices
   (the ring's sizing contract), the child acquires before writing, the
   learner's ``Rollout.release`` returns them after consuming.
+
+One process per chip: a chip belongs to one process at a time, and the
+parent learner holds it. So each child pins its JAX to the CPU platform as
+its first act, before any array exists: workers act on the host CPU and
+never initialise the accelerator.
 
 Child lifecycle: workers are spawned once per ``PipelinedRL`` (spawn
 context — fork would duplicate JAX runtime state) and persist across
@@ -105,7 +112,11 @@ def _worker_main(spec: HostEnvSpec, arch_cfg, hp, slot_handle,
                  cmd_q, ready_q, free_q, stop_evt, actor_id: int) -> None:
     """Child entry point: rebuild the env pool + acting step, then serve
     ``run`` commands until ``stop`` (or the parent disappears)."""
-    import jax.numpy as jnp  # deferred: spawned child initializes its own JAX
+    import jax
+
+    # first act, before any array exists: the parent holds the accelerator
+    jax.config.update("jax_platforms", "cpu")
+    import jax.numpy as jnp
 
     from repro.core.agents.paac import PAACAgent
     from repro.pipeline.actor import collect_host, make_host_act_step
@@ -139,6 +150,7 @@ def _worker_main(spec: HostEnvSpec, arch_cfg, hp, slot_handle,
         if pool is not None:
             pool.close()
         return
+    ready_q.put(("ready", jax.devices()[0].platform))
     try:
         while True:
             try:
@@ -255,6 +267,7 @@ class _WorkerHandle:
         self.free_q = free_q
         self.stop_evt = stop_evt
         self.sets = sets  # parent-side views of the same shm blocks
+        self.platform: Optional[str] = None  # from the child's "ready"
 
 
 class ProcessActorDrainer(ActorBase):
@@ -329,6 +342,8 @@ class ProcessActorDrainer(ActorBase):
                     self.produced += 1
                     if self._ledger is not None:
                         self._ledger.produced()
+            elif kind == "ready":
+                self._worker.platform = msg[1]
             elif kind == "spans":
                 # the child's telemetry ring, shipped just before its
                 # terminal message: give it a trace track of its own process
@@ -478,6 +493,11 @@ class ProcessActorPlane:
     @property
     def n_workers(self) -> int:
         return len(self._workers)
+
+    def worker_platforms(self) -> List[Optional[str]]:
+        """The JAX platform each worker reported (``None`` until a run has
+        drained its ``ready`` message)."""
+        return [w.platform for w in self._workers]
 
     def begin_run(self, queue, quota: Sequence[int], lockstep: bool,
                   params: Any, telemetry=None, ledger=None, injector=None):

@@ -163,9 +163,12 @@ class DecodeEngine:
         """One fixed-width decode step over every slot (leased or idle).
         Tokens land in the device-side ring log; nothing returns to host."""
         row = self._glob % self.max_len
+        # copies: the dispatch is asynchronous and may read a host array
+        # in place after the bookkeeping below (or the next admit) has
+        # already mutated it
         toks, self._cache, self._log = self._step_fn(
-            self.params, self._cache, self._tokens, self._pos,
-            self._seeds, self._tindex, self._log, row)
+            self.params, self._cache, self._tokens, self._pos.copy(),
+            self._seeds.copy(), self._tindex.copy(), self._log, row)
         self._tokens = toks[:, None]
         self._pos += 1
         self._tindex += 1

@@ -7,6 +7,7 @@ from repro.utils.tree import (
     tree_scale,
 )
 from repro.utils.logging import get_logger
+from repro.utils.compile_cache import use_compile_cache
 
 __all__ = [
     "tree_size",
@@ -16,4 +17,5 @@ __all__ = [
     "tree_add",
     "tree_scale",
     "get_logger",
+    "use_compile_cache",
 ]

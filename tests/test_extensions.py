@@ -29,7 +29,7 @@ def test_mla_decode_kernel(S, H, Rk, Rr, pos, dtype, key):
     cc = jax.random.normal(key, (B, S, Rk), dtype)
     kr = jax.random.normal(key, (B, S, Rr), dtype)
     out = mla_decode_attention_pallas(q_lat, q_rope, cc, kr, pos, scale,
-                                      block_k=128)
+                                      block_k=128, interpret=True)
     ref = R.mla_decode_attention_ref(q_lat, q_rope, cc, kr, pos, scale)
     tol = 3e-2 if dtype == jnp.bfloat16 else 3e-5
     np.testing.assert_allclose(out.astype(jnp.float32),
@@ -51,7 +51,7 @@ def test_mla_decode_kernel_matches_model_absorb_path(key):
     cc = jax.random.normal(key, (B, S, Rk))
     kr = jax.random.normal(key, (B, S, Rr))
     out_k = mla_decode_attention_pallas(q_lat, q_rope, cc, kr, S - 1, scale,
-                                        block_k=32)
+                                        block_k=32, interpret=True)
     ref = R.mla_decode_attention_ref(q_lat, q_rope, cc, kr, S - 1, scale)
     np.testing.assert_allclose(out_k, ref, rtol=2e-5, atol=2e-5)
 

@@ -16,13 +16,13 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
 # ---------------------------------------------------------------- nstep
-@pytest.mark.parametrize("E,T", [(1, 1), (7, 5), (32, 64), (33, 17)])
+@pytest.mark.parametrize("E,T", [(1, 1), (7, 5), (32, 64), (33, 17), (300, 5)])
 @pytest.mark.parametrize("gamma", [0.9, 0.99])
 def test_nstep_returns(E, T, gamma, key):
     r = jax.random.normal(key, (E, T))
     d = jax.random.bernoulli(key, 0.3, (E, T))
     b = jax.random.normal(key, (E,))
-    out = nstep_returns_pallas(r, d, b, gamma, block_e=8)
+    out = nstep_returns_pallas(r, d, b, gamma, block_e=8, interpret=True)
     ref = R.nstep_returns_ref(r, d, b, gamma)
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
@@ -33,11 +33,11 @@ def test_nstep_matches_paper_hand_example():
     r = jnp.array([[1.0, 0.0, 2.0]])
     d = jnp.zeros((1, 3), bool)
     b = jnp.array([4.0])
-    out = nstep_returns_pallas(r, d, b, 0.5)
+    out = nstep_returns_pallas(r, d, b, 0.5, interpret=True)
     np.testing.assert_allclose(out[0], [2.0, 2.0, 4.0])
     # terminal at t=1 cuts the bootstrap: R2 = 0 (done), R1 = 1 + .5*0
     d = jnp.array([[False, True, False]])
-    out = nstep_returns_pallas(r, d, b, 0.5)
+    out = nstep_returns_pallas(r, d, b, 0.5, interpret=True)
     np.testing.assert_allclose(out[0], [1.0, 0.0, 4.0])
 
 
@@ -56,7 +56,7 @@ def test_flash_attention(Sq, Sk, H, Hkv, D, dtype, window, key):
     k = jax.random.normal(key, (B, Sk, Hkv, D), dtype)
     v = jax.random.normal(key, (B, Sk, Hkv, D), dtype)
     out = flash_attention_pallas(q, k, v, causal=True, window=window,
-                                 block_q=64, block_k=64)
+                                 block_q=64, block_k=64, interpret=True)
     ref = R.flash_attention_ref(q, k, v, causal=True, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(
@@ -69,7 +69,8 @@ def test_flash_non_causal(key):
     q = jax.random.normal(key, (B, S, H, D))
     k = jax.random.normal(key, (B, S, H, D))
     v = jax.random.normal(key, (B, S, H, D))
-    out = flash_attention_pallas(q, k, v, causal=False, block_q=32, block_k=32)
+    out = flash_attention_pallas(q, k, v, causal=False, block_q=32, block_k=32,
+                                 interpret=True)
     ref = R.flash_attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
@@ -86,7 +87,7 @@ def test_decode_attention(S, H, Hkv, D, pos, dtype, key):
     q = jax.random.normal(key, (B, H, D), dtype)
     kc = jax.random.normal(key, (B, S, Hkv, D), dtype)
     vc = jax.random.normal(key, (B, S, Hkv, D), dtype)
-    out = decode_attention_pallas(q, kc, vc, pos, block_k=128)
+    out = decode_attention_pallas(q, kc, vc, pos, block_k=128, interpret=True)
     ref = R.decode_attention_ref(q, kc, vc, pos)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(
@@ -108,7 +109,7 @@ def test_ssd_scan(S, H, P, N, chunk, key):
     Bm = jax.random.normal(key, (B, S, N))
     Cm = jax.random.normal(key, (B, S, N))
     Dv = jnp.ones((H,))
-    y = ssd_scan_pallas(x, dt, A_log, Bm, Cm, Dv, chunk=chunk)
+    y = ssd_scan_pallas(x, dt, A_log, Bm, Cm, Dv, chunk=chunk, interpret=True)
     ref, _ = R.ssd_scan_ref(x, dt, A_log, Bm, Cm, Dv)
     np.testing.assert_allclose(y, ref, rtol=1e-4, atol=2e-3)
 
@@ -124,7 +125,8 @@ def test_ssd_scan_matches_model_chunked(key):
     Bm = jax.random.normal(key, (B, S, N))
     Cm = jax.random.normal(key, (B, S, N))
     Dv = jnp.ones((H,))
-    y_k = ssd_scan_pallas(x, dt, A_log, Bm, Cm, Dv, chunk=32)
+    y_k = ssd_scan_pallas(x, dt, A_log, Bm, Cm, Dv, chunk=32,
+                          interpret=True)
     y_m, state_m = ssd_chunked(x, dt, A_log, Bm, Cm, Dv, chunk=32)
     y_r, state_r = R.ssd_scan_ref(x, dt, A_log, Bm, Cm, Dv)
     np.testing.assert_allclose(y_k, y_r, rtol=1e-4, atol=1e-4)

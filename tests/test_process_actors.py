@@ -169,6 +169,21 @@ def test_process_backend_zero_quota_workers_check_out():
     assert sorted(prl.learned_ids) == [(0, 0), (1, 0)]
 
 
+def test_worker_child_pins_its_jax_to_the_cpu(monkeypatch):
+    """One process per chip: the parent learner holds the accelerator, so
+    every worker pins its JAX to the CPU before any array exists. The
+    children inherit a JAX_PLATFORMS naming no real platform: without the
+    pin they could not act at all."""
+    monkeypatch.setenv("JAX_PLATFORMS", "no-such-platform")
+    spec = py_bound_spec(4, obs_dim=4, spin=0, n_workers=2)
+    with PipelinedRL(spec, _vector_agent(), lr_schedule=None, seed=0,
+                     pipeline=_pipe(num_actors=2)) as prl:
+        assert prl._process_plane.worker_platforms() == [None, None]
+        res = prl.run(2)
+        assert prl._process_plane.worker_platforms() == ["cpu", "cpu"]
+    assert np.isfinite(res.mean_metrics["loss"])
+
+
 # ---------------------------------------------------------------------------
 # equivalence pin (acceptance): process lockstep == thread lockstep, bitwise
 # ---------------------------------------------------------------------------

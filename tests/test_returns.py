@@ -177,7 +177,7 @@ def test_vtrace_pallas_matches_reference_scan(rewards, dones, values,
     args = (jnp.asarray(rewards), jnp.asarray(dones), jnp.asarray(values),
             jnp.asarray(bootstrap), rho, gamma, rho_bar, c_bar)
     vs_ref, adv_ref = vtrace_returns(*args)
-    vs_k, adv_k = vtrace_returns_pallas(*args, block_e=2)
+    vs_k, adv_k = vtrace_returns_pallas(*args, block_e=2, interpret=True)
     np.testing.assert_allclose(vs_k, vs_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(adv_k, adv_ref, rtol=1e-5, atol=1e-5)
 

@@ -9,9 +9,8 @@ from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.distributed.sharding import cache_specs, input_sharding, param_specs
 from repro.models import init_policy, init_policy_cache
 
-# jax 0.4.37 signature: AbstractMesh(((name, size), ...))
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _params_sds(cfg):

@@ -108,7 +108,8 @@ def test_c_bar_zero_is_one_step_td():
 
 
 # ---------------------------------------------------------------- kernel
-@pytest.mark.parametrize("E,T", [(1, 1), (5, 9), (32, 33), (17, 8)])
+@pytest.mark.parametrize("E,T", [(1, 1), (5, 9), (32, 33), (17, 8),
+                                 (300, 5)])
 @pytest.mark.parametrize("rho_bar,c_bar", [(1.0, 1.0), (2.0, 1.0),
                                            (1e9, 1e9)])
 def test_vtrace_kernel_matches_scan_and_ref(E, T, rho_bar, c_bar):
@@ -117,7 +118,7 @@ def test_vtrace_kernel_matches_scan_and_ref(E, T, rho_bar, c_bar):
             0.97, rho_bar, c_bar)
     vs_scan, adv_scan = vtrace_returns(*args)
     vs_ref, adv_ref = R.vtrace_returns_ref(*args)
-    vs_k, adv_k = vtrace_returns_pallas(*args, block_e=8)
+    vs_k, adv_k = vtrace_returns_pallas(*args, block_e=8, interpret=True)
     np.testing.assert_allclose(vs_scan, vs_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(adv_scan, adv_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(vs_k, vs_scan, rtol=1e-5, atol=1e-5)
