@@ -101,6 +101,33 @@ def trajectory_forward(params, cfg, hp, traj, bootstrap):
     return logits, values, actions, returns
 
 
+def learn(act, loss_fn, optimizer, lr_schedule, params, opt_state, traj,
+          last_obs, step):
+    """The learning half of one iteration (Algorithm 1 lines 11-19).
+
+    Bootstraps V(s_{tmax+1}) from ``last_obs``, takes ``loss_fn(params,
+    traj, bootstrap) -> (loss, metrics)``'s gradient and applies one
+    optimizer step. Shared by the fused train step and the pipelined
+    learner (``repro.pipeline.learner``). Runs under the ``learner`` name
+    scope, with ``loss_grad`` around the bootstrap forward and the gradient
+    (the returns included) and ``optimizer`` around the update.
+    """
+    with jax.named_scope("learner"):
+        with jax.named_scope("loss_grad"):
+            _, bootstrap = act(params, last_obs)
+            bootstrap = jax.lax.stop_gradient(bootstrap)
+            (loss, metrics), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, traj, bootstrap)
+        lr = lr_schedule(step)
+        with jax.named_scope("optimizer"):
+            params, opt_state = optimizer.update(grads, opt_state, params, lr)
+        metrics = dict(metrics)
+        metrics["loss"] = loss
+        metrics["reward_sum"] = jnp.sum(traj.reward)
+        metrics["episodes"] = jnp.sum(traj.done)
+    return params, opt_state, metrics
+
+
 class PAACAgent(Agent):
     """The paper's agent. model cfg + hyperparameters -> jittable steps."""
 
@@ -143,17 +170,10 @@ class PAACAgent(Agent):
             env_state, last_obs, key, traj = rollout(
                 act, env, params, env_state, obs, key, hp.t_max
             )
-            _, bootstrap = act(params, last_obs)  # V(s_{tmax+1})
-            bootstrap = jax.lax.stop_gradient(bootstrap)
-            (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, traj, bootstrap
+            params, opt_state, metrics = learn(
+                act, loss_fn, optimizer, lr_schedule, params, opt_state,
+                traj, last_obs, step,
             )
-            lr = lr_schedule(step)
-            params, opt_state = optimizer.update(grads, opt_state, params, lr)
-            metrics = dict(metrics)
-            metrics["loss"] = loss
-            metrics["reward_sum"] = jnp.sum(traj.reward)
-            metrics["episodes"] = jnp.sum(traj.done)
             return params, opt_state, env_state, last_obs, key, metrics
 
         return train_step

@@ -17,6 +17,13 @@ Two environment regimes:
 
 The run-loop metrics accounting is shared with ``repro.pipeline`` through
 ``MetricsAccumulator`` so both backends report identical ``RunResult``s.
+
+``ParallelRL.run`` records its host loop into a ``repro.telemetry``
+emitter (track ``driver`` of a per-run hub, kept as ``self.telemetry``):
+``step.dispatch`` around the train step's call, ``metrics.read`` around
+the host's read of the update's metrics. ``RunResult.dispatch_s`` /
+``readback_s`` are those spans' totals. While the JAX profiler collects,
+the spans and a ``StepTraceAnnotation`` per iteration land in the profile.
 """
 from __future__ import annotations
 
@@ -33,6 +40,12 @@ from repro.core.agents.baselines import LaggedPAACAgent
 from repro.envs.host_env import HostEnvPool
 from repro.models import init_policy
 from repro.optim import make_optimizer
+from repro.telemetry import (
+    METRICS_READ,
+    STEP_DISPATCH,
+    Telemetry,
+    step_annotation,
+)
 from repro.utils import get_logger
 
 log = get_logger("framework")
@@ -53,6 +66,13 @@ class RunResult:
     actor_idle_s: float = 0.0
     learner_idle_s: float = 0.0
     per_actor_idle_s: List[float] = field(default_factory=list)
+    # synchronous driver accounting (0 for the pipeline): host time in the
+    # train step's dispatch and in reading the update's metrics back (span
+    # totals), and the device-to-host transfers those reads made (every
+    # backend: one per device metric scalar brought to the host)
+    dispatch_s: float = 0.0
+    readback_s: float = 0.0
+    host_reads: int = 0
 
 
 class MetricsAccumulator:
@@ -81,6 +101,7 @@ class MetricsAccumulator:
         self.episodes = 0.0
         self.iters = 0
         self.lazy = lazy
+        self.host_reads = 0  # device arrays brought to the host by _fold
         self._pending: List[Dict] = []
         self._last: Dict = {}  # most recently *folded* metrics dict
         self._t0 = time.perf_counter()
@@ -93,9 +114,15 @@ class MetricsAccumulator:
         self._fold(metrics)
 
     def _fold(self, metrics: Dict) -> None:
+        # each value is read once: float() of a device array is one
+        # device-to-host transfer (one ``np.asarray(jax.Array)`` event in a
+        # profile)
+        host = {}
         for k, v in metrics.items():
-            self.acc[k] = self.acc.get(k, 0.0) + float(v)
-        self.episodes += float(metrics.get("episodes", 0.0))
+            self.host_reads += isinstance(v, jax.Array)
+            host[k] = float(v)
+            self.acc[k] = self.acc.get(k, 0.0) + host[k]
+        self.episodes += host.get("episodes", 0.0)
         self._last = metrics
 
     def _drain(self) -> None:
@@ -146,6 +173,7 @@ class MetricsAccumulator:
             episodes=self.episodes,
             mean_metrics=mean,
             timesteps_per_sec=steps_per_iter * self.iters / max(dt, 1e-9),
+            host_reads=self.host_reads,
             **extra,
         )
 
@@ -249,52 +277,84 @@ class ParallelRL:
         self.total_steps = 0
         self._steps_per_iter = env.n_envs * agent.hp.t_max
 
-    # -- one iteration on the HostEnvPool path -------------------------------
-    def _host_iteration(self, step_arr):
+    # -- the HostEnvPool path's host rollout ---------------------------------
+    def _host_collect(self):
         self.obs, self.key, traj, last_obs = self._collect_host(
             self._act, self.env, self.params, self.obs, self.key,
             self.agent.hp.t_max, staging=self._staging,
         )
-        self.params, self.opt_state, metrics = self._update_step(
-            self.params, self.opt_state, traj, last_obs, step_arr
-        )
+        return traj, last_obs
+
+    def compiled(self):
+        """The train step as XLA compiled it for this job
+        (``jax.stages.Compiled``; its ``as_text()`` names every instruction
+        with the ``op_name`` scope path it came from). A repeat of the
+        program ``run`` compiled is answered by the compile cache."""
+        if self._host:
+            raise NotImplementedError(
+                "the HostEnvPool path has no fused train step")
+        state = (self.params, self.opt_state) + (
+            (self.agent_state,) if self._has_agent_state else ()
+        ) + (self.env_state, self.obs, self.key)
+        step = jnp.asarray(0, jnp.int32)
+        return self._train_step.lower(*state, step).compile()
+
+    def _dispatch(self, step_arr, batch=None):
+        """One call of the jitted step: the fused train step, or on the
+        HostEnvPool path the update on the collected ``batch``."""
+        if self._host:
+            traj, last_obs = batch
+            self.params, self.opt_state, metrics = self._update_step(
+                self.params, self.opt_state, traj, last_obs, step_arr
+            )
+        elif self._has_agent_state:
+            (
+                self.params,
+                self.opt_state,
+                self.agent_state,
+                self.env_state,
+                self.obs,
+                self.key,
+                metrics,
+            ) = self._train_step(
+                self.params, self.opt_state, self.agent_state,
+                self.env_state, self.obs, self.key, step_arr,
+            )
+        else:
+            (
+                self.params,
+                self.opt_state,
+                self.env_state,
+                self.obs,
+                self.key,
+                metrics,
+            ) = self._train_step(
+                self.params, self.opt_state, self.env_state, self.obs,
+                self.key, step_arr,
+            )
         return metrics
 
     def run(self, iterations: int, log_every: int = 0) -> RunResult:
         """Run `iterations` framework iterations (each = n_e·t_max timesteps)."""
+        # fresh telemetry hub per run, kept on self like PipelinedRL's
+        self.telemetry = Telemetry()
+        spans = self.telemetry.emitter("driver")
         acc = MetricsAccumulator()
         step_arr = jnp.asarray(self.total_steps, jnp.int32)
+        batch = None
         for i in range(iterations):
-            if self._host:
-                metrics = self._host_iteration(step_arr)
-            elif self._has_agent_state:
-                (
-                    self.params,
-                    self.opt_state,
-                    self.agent_state,
-                    self.env_state,
-                    self.obs,
-                    self.key,
-                    metrics,
-                ) = self._train_step(
-                    self.params, self.opt_state, self.agent_state,
-                    self.env_state, self.obs, self.key, step_arr,
-                )
-            else:
-                (
-                    self.params,
-                    self.opt_state,
-                    self.env_state,
-                    self.obs,
-                    self.key,
-                    metrics,
-                ) = self._train_step(
-                    self.params, self.opt_state, self.env_state, self.obs,
-                    self.key, step_arr,
-                )
-            self.total_steps += self._steps_per_iter
-            step_arr = step_arr + 1
-            acc.update(metrics)
+            with step_annotation("train",
+                                 self.total_steps // self._steps_per_iter):
+                if self._host:
+                    batch = self._host_collect()
+                spans.begin(STEP_DISPATCH)
+                metrics = self._dispatch(step_arr, batch)
+                step_arr = step_arr + 1
+                spans.end()
+                self.total_steps += self._steps_per_iter
+                spans.begin(METRICS_READ)
+                acc.update(metrics)
+                spans.end()
             if log_every and (i + 1) % log_every == 0:
                 log.info(
                     "iter %d steps %d reward_sum %.3f loss %.4f",
@@ -302,4 +362,6 @@ class ParallelRL:
                     acc.acc.get("reward_sum", 0.0),
                     float(metrics.get("loss", 0.0)),
                 )
-        return acc.result(self.total_steps, self._steps_per_iter)
+        return acc.result(self.total_steps, self._steps_per_iter,
+                          dispatch_s=spans.total(STEP_DISPATCH),
+                          readback_s=spans.total(METRICS_READ))

@@ -10,7 +10,11 @@ One ``lax.scan`` iteration = one framework timestep:
      vmapped env step fused into the same XLA program.
 
 Because environments are JAX-native, acting, stepping and (in the agents)
-learning compile into a single device program per PAAC iteration.
+learning compile into a single device program per PAAC iteration. The
+scan runs under the ``rollout`` name scope, its body's policy call under
+``policy.forward`` and the environment step (auto-reset and frame stacking
+included) under ``env.step``: the names every op of the program carries in
+its HLO ``op_name``, by which a device trace splits the step's time.
 """
 from __future__ import annotations
 
@@ -46,21 +50,26 @@ def rollout(
     def step(carry, _):
         env_state, obs, key = carry
         key, k_act, k_env = jax.random.split(key, 3)
-        logits, value = act_fn(params, obs)
-        action = jax.random.categorical(k_act, logits)
-        # behaviour log-prob from the sampled action's logit alone:
-        # log π(a|s) = logits[a] − logsumexp(logits). Gathering first keeps
-        # the acting scan from materializing the full (E, A) log_softmax
-        # matrix when only one column per row is ever read.
-        action_logit = jnp.take_along_axis(logits, action[:, None], axis=1)[:, 0]
-        logp = action_logit - jax.scipy.special.logsumexp(logits, axis=1)
-        env_state, next_obs, reward, done = env.step(env_state, action, k_env)
+        with jax.named_scope("policy.forward"):
+            logits, value = act_fn(params, obs)
+            action = jax.random.categorical(k_act, logits)
+            # behaviour log-prob from the sampled action's logit alone:
+            # log π(a|s) = logits[a] − logsumexp(logits). Gathering first
+            # keeps the acting scan from materializing the full (E, A)
+            # log_softmax matrix when only one column per row is ever read.
+            action_logit = jnp.take_along_axis(logits, action[:, None],
+                                               axis=1)[:, 0]
+            logp = action_logit - jax.scipy.special.logsumexp(logits, axis=1)
+        with jax.named_scope("env.step"):
+            env_state, next_obs, reward, done = env.step(env_state, action,
+                                                         k_env)
         tr = Transition(obs, action, reward, done, value, logp)
         return (env_state, next_obs, key), tr
 
-    (env_state, obs, key), traj = jax.lax.scan(
-        step, (env_state, obs, key), None, length=t_max
-    )
+    with jax.named_scope("rollout"):
+        (env_state, obs, key), traj = jax.lax.scan(
+            step, (env_state, obs, key), None, length=t_max
+        )
     return env_state, obs, key, traj
 
 

@@ -26,11 +26,13 @@ plane across ``D`` accelerators: one actor lane per device feeds a
 per-device sub-ring, the learner consumes a globally-sharded batch and
 all-reduces its gradients over the mesh's data axis (on CPU, expose fake
 devices first: ``XLA_FLAGS=--xla_force_host_platform_device_count=D``).
-``--trace``/``--metrics-jsonl``/``--stall-timeout`` turn on the pipeline's
-observability exports (``repro.telemetry``; see docs/observability.md): a
-Perfetto-viewable Chrome trace of every plane's spans, a JSONL liveness
-heartbeat, and the stall watchdog naming the stage each party is blocked
-in when progress stops.
+``--trace PATH`` writes a Perfetto-viewable Chrome trace of the run's
+spans (``repro.telemetry``; see docs/observability.md): every plane's
+under ``--pipeline``, the synchronous driver's ``driver`` track
+(``step.dispatch``, ``metrics.read``) without it. ``--metrics-jsonl`` and
+``--stall-timeout`` turn on the pipeline's JSONL liveness heartbeat and
+the stall watchdog naming the stage each party is blocked in when
+progress stops.
 
 ``--elastic`` arms the pipeline's actor supervisor: crashed replicas
 respawn under ``--restart-budget`` (exponential ``--restart-backoff``),
@@ -100,11 +102,10 @@ def run_rl(args):
         raise SystemExit(
             "--mesh is a pipeline (mesh rollout plane) knob: add --pipeline"
         )
-    if (args.trace or args.metrics_jsonl or args.stall_timeout) \
-            and not args.pipeline:
+    if (args.metrics_jsonl or args.stall_timeout) and not args.pipeline:
         raise SystemExit(
-            "--trace/--metrics-jsonl/--stall-timeout observe the pipeline "
-            "backend's telemetry hub: add --pipeline"
+            "--metrics-jsonl/--stall-timeout observe the pipeline "
+            "backend's actors and learner: add --pipeline"
         )
     if args.sanitize and not args.pipeline:
         raise SystemExit(
@@ -247,6 +248,9 @@ def run_rl(args):
                     continue
             res = rl.run(iters,
                          log_every=max(args.iterations // 4, 1))
+            if args.trace and not args.pipeline:
+                # the pipeline writes its own (PipelineConfig.trace_path)
+                rl.telemetry.write_trace(args.trace)
             log.info(
                 "epoch %d steps=%d mean_reward/iter=%.3f tps=%.0f%s",
                 epoch, res.steps, res.mean_metrics.get("reward_sum", 0.0),
@@ -315,7 +319,7 @@ def run_synthetic(args):
     )
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ASSIGNED_ARCHS + ["paac_vector"],
                     default="mamba2-370m")
@@ -378,7 +382,8 @@ def main():
                     "emulator cost model)")
     ap.add_argument("--trace", default="",
                     help="write a Chrome trace-event JSON of the run's spans "
-                    "here (open in Perfetto); pipeline backend only")
+                    "here (open in Perfetto): the pipeline's planes, or the "
+                    "synchronous driver's dispatch and metric reads")
     ap.add_argument("--metrics-jsonl", default="",
                     help="append a JSONL metrics heartbeat (steps/s EMA, "
                     "queue depth, staleness, per-actor liveness) here")
@@ -427,7 +432,7 @@ def main():
                     help="restore the newest checkpoint in --checkpoint-dir "
                     "and run only the remaining iterations (bitwise "
                     "continuation on the thread backend's FIFO planes)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     use_compile_cache()
     if args.mode == "rl":
         run_rl(args)

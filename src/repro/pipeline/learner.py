@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.agents.paac import (
+    learn,
     paac_losses,
     trajectory_forward,
     trajectory_logits_values,
@@ -148,18 +149,10 @@ def make_learner_step(agent, optimizer, lr_schedule, rho_bar: float = 1.0,
         return total, metrics
 
     def _update(params, opt_state, traj, last_obs, step):
-        _, bootstrap = act(params, last_obs)  # V(s_{tmax+1}) under learner params
-        bootstrap = jax.lax.stop_gradient(bootstrap)
-        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, traj, bootstrap
-        )
-        lr = lr_schedule(step)
-        params, opt_state = optimizer.update(grads, opt_state, params, lr)
-        metrics = dict(metrics)
-        metrics["loss"] = loss
-        metrics["reward_sum"] = jnp.sum(traj.reward)
-        metrics["episodes"] = jnp.sum(traj.done)
-        return params, opt_state, metrics
+        # V(s_{tmax+1}) under learner params, then the same learning half
+        # (and name scopes) as the fused synchronous train step
+        return learn(act, loss_fn, optimizer, lr_schedule, params, opt_state,
+                     traj, last_obs, step)
 
     if not fused_publish:
         return _update

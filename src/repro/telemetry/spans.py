@@ -31,13 +31,23 @@ recording happens:
 every ``RunResult`` field) keep accumulating, but ring storage,
 stack bookkeeping for the watchdog, and last-activity tracking are
 skipped — the pre-refactor cost model.
+
+While the JAX profiler collects, every ``begin``/``end`` pair also opens
+and closes a profiler ``TraceAnnotation`` named by its category, so the
+spans land in the profile's host plane on the profiler's clock beside the
+device's ops. The gate is ``TraceAnnotation.is_enabled()``: with the
+profiler off no annotation object is built. ``record()`` spans are
+written after the fact and stay out of the profile.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from array import array
 from typing import List, Optional, Sequence, Tuple
+
+from jax import profiler as _profiler
 
 __all__ = [
     "CATEGORIES",
@@ -55,9 +65,12 @@ __all__ = [
     "FAULT_DETECT",
     "FAULT_RESPAWN",
     "FAULT_GIVEUP",
+    "STEP_DISPATCH",
+    "METRICS_READ",
     "SpanEmitter",
     "set_capture",
     "capture_enabled",
+    "step_annotation",
 ]
 
 # the fixed pipeline vocabulary — every plane speaks these stages (an
@@ -67,9 +80,13 @@ __all__ = [
 # deposit), sample (the learner's batched draw over resident slots) and
 # evict (FIFO retirement of the oldest slot when the ring is full). The
 # three fault.* stages are the supervisor's recovery episodes (detect a
-# replica failure, respawn it, or give up and degrade) — appended, never
-# reordered: shipped worker rings carry category *indices*, so existing
-# entries must keep their positions across versions.
+# replica failure, respawn it, or give up and degrade). The synchronous
+# driver's (``ParallelRL.run``) two stages: step.dispatch (the jitted
+# train step's call and the step counter's increment) and metrics.read
+# (the host converting the update's metric scalars, which waits for the
+# update to finish). Appended, never reordered: shipped worker rings carry
+# category *indices*, so existing entries must keep their positions
+# across versions.
 CATEGORIES: Tuple[str, ...] = (
     "collect",
     "queue.put_wait",
@@ -85,6 +102,8 @@ CATEGORIES: Tuple[str, ...] = (
     "fault.detect",
     "fault.respawn",
     "fault.giveup",
+    "step.dispatch",
+    "metrics.read",
 )
 COLLECT = 0
 QUEUE_PUT_WAIT = 1
@@ -100,6 +119,8 @@ REPLAY_EVICT = 10
 FAULT_DETECT = 11
 FAULT_RESPAWN = 12
 FAULT_GIVEUP = 13
+STEP_DISPATCH = 14
+METRICS_READ = 15
 
 _MAX_DEPTH = 8  # open-span nesting the preallocated stack covers
 
@@ -118,6 +139,18 @@ def capture_enabled() -> bool:
     return _capture
 
 
+_NO_STEP = contextlib.nullcontext()
+
+
+def step_annotation(name: str, step_num: int):
+    """A profiler ``StepTraceAnnotation`` (TensorBoard's step view) while
+    the JAX profiler collects; otherwise a shared no-op context, so nothing
+    is built per step."""
+    if _profiler.TraceAnnotation.is_enabled():
+        return _profiler.StepTraceAnnotation(name, step_num=step_num)
+    return _NO_STEP
+
+
 class SpanEmitter:
     """Bounded span ring + per-category duration totals for one track.
 
@@ -131,7 +164,7 @@ class SpanEmitter:
     __slots__ = (
         "name", "categories", "capacity", "drops", "count",
         "_cat", "_t0", "_t1", "_totals",
-        "_stack_cat", "_stack_t0", "_depth",
+        "_stack_cat", "_stack_t0", "_stack_tm", "_depth",
         "last_activity", "_lock",
     )
 
@@ -151,6 +184,8 @@ class SpanEmitter:
         self._totals = array("d", bytes(8 * len(self.categories)))
         self._stack_cat = array("i", bytes(4 * _MAX_DEPTH))
         self._stack_t0 = array("d", bytes(8 * _MAX_DEPTH))
+        # the open profiler annotation of each depth (None: profiler off)
+        self._stack_tm: List = [None] * _MAX_DEPTH
         self._depth = 0
         self.last_activity = 0.0  # perf_counter of the last recorded end
         self._lock = threading.Lock() if locked else None
@@ -164,6 +199,10 @@ class SpanEmitter:
         self._depth = d + 1
         if d < _MAX_DEPTH:
             self._stack_cat[d] = cat
+            if _profiler.TraceAnnotation.is_enabled():
+                tm = self._stack_tm[d] = _profiler.TraceAnnotation(
+                    self.categories[cat])
+                tm.__enter__()
             self._stack_t0[d] = time.perf_counter()
         else:
             self.drops += 1
@@ -174,13 +213,22 @@ class SpanEmitter:
         self._depth = d
         if d < 0 or d >= _MAX_DEPTH:
             return  # over/underflow: the matching begin already counted it
-        self._record(self._stack_cat[d], self._stack_t0[d],
-                     time.perf_counter())
+        t1 = time.perf_counter()
+        tm = self._stack_tm[d]
+        if tm is not None:
+            tm.__exit__(None, None, None)
+            self._stack_tm[d] = None
+        self._record(self._stack_cat[d], self._stack_t0[d], t1)
 
     def cancel(self) -> None:
         """Close the innermost open span *without* recording it (abort
-        paths whose pre-telemetry counters never accumulated either)."""
-        self._depth -= 1
+        paths whose pre-telemetry counters never accumulated either). Its
+        profiler annotation, if one is open, still closes."""
+        d = self._depth - 1
+        self._depth = d
+        if 0 <= d < _MAX_DEPTH and self._stack_tm[d] is not None:
+            self._stack_tm[d].__exit__(None, None, None)
+            self._stack_tm[d] = None
 
     def record(self, cat: int, t0: float, t1: Optional[float] = None) -> None:
         """After-the-fact span (the multi-writer path: ``locked=True``)."""
@@ -258,5 +306,6 @@ class SpanEmitter:
         self.count = 0
         self.drops = 0
         self._depth = 0
+        self._stack_tm = [None] * _MAX_DEPTH
         for i in range(len(self._totals)):
             self._totals[i] = 0.0
