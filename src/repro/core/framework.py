@@ -18,12 +18,21 @@ Two environment regimes:
 The run-loop metrics accounting is shared with ``repro.pipeline`` through
 ``MetricsAccumulator`` so both backends report identical ``RunResult``s.
 
+On the fused path ``ParallelRL.run`` reads each update's metrics one
+update late: the host copy of update k's scalars starts as k is
+dispatched, and they are folded once update k+1 is queued behind it, so
+the device never drains while the host reads. The ``HostEnvPool`` path
+folds each update before its next collect (its staging set is reused).
+
 ``ParallelRL.run`` records its host loop into a ``repro.telemetry``
 emitter (track ``driver`` of a per-run hub, kept as ``self.telemetry``):
 ``step.dispatch`` around the train step's call, ``metrics.read`` around
-the host's read of the update's metrics. ``RunResult.dispatch_s`` /
-``readback_s`` are those spans' totals. While the JAX profiler collects,
-the spans and a ``StepTraceAnnotation`` per iteration land in the profile.
+the host's fold of an update's metrics (the previous update's, on the
+fused path). ``RunResult.dispatch_s`` / ``readback_s`` are those spans'
+totals, and ``reads_waited`` (hub counter ``reads_waited``) counts the
+deferred folds that still waited on the device. While the JAX profiler
+collects, the spans and a ``StepTraceAnnotation`` per iteration land in
+the profile.
 """
 from __future__ import annotations
 
@@ -50,6 +59,9 @@ from repro.utils import get_logger
 
 log = get_logger("framework")
 
+# hub counter of the sync driver's deferred folds that waited on the device
+READS_WAITED = "reads_waited"
+
 
 @dataclass
 class RunResult:
@@ -73,6 +85,11 @@ class RunResult:
     dispatch_s: float = 0.0
     readback_s: float = 0.0
     host_reads: int = 0
+    # deferred folds of the fused sync path whose update had not retired
+    # when the fold began: the host waited on the device, with the next
+    # update already queued behind it (0 on the HostEnvPool path, which
+    # defers nothing, and for the pipeline)
+    reads_waited: int = 0
 
 
 class MetricsAccumulator:
@@ -85,11 +102,12 @@ class MetricsAccumulator:
 
     ``lazy=True`` defers the host conversion of device metric scalars: each
     ``update`` only stashes the dict, and the blocking ``float()`` reads
-    happen once, in ``result``. Eager mode forces a device sync every
-    iteration — which the synchronous loop doesn't notice (it waits for the
-    update anyway) and the host queue plane *requires* (consume-completion
-    gates the staging ``release`` protocol), but which would serialize the
-    device-ring learner against every update it dispatches. Lazy draining
+    happen once, in ``result``. Eager mode waits for the update it is
+    given. The host queue plane and the synchronous ``HostEnvPool`` path
+    *require* that (consume-completion gates the reuse of their staging
+    buffers); the fused synchronous loop hands each update over one update
+    late, so the wait overlaps the next one; the device-ring learner would
+    be serialized against every update it dispatches. Lazy draining
     accumulates in exactly the same host-side float arithmetic, so the two
     modes report bit-identical metrics; the wall clock is read *after* the
     drain, so timesteps/s still covers the full execution, not just the
@@ -103,7 +121,7 @@ class MetricsAccumulator:
         self.lazy = lazy
         self.host_reads = 0  # device arrays brought to the host by _fold
         self._pending: List[Dict] = []
-        self._last: Dict = {}  # most recently *folded* metrics dict
+        self._last: Dict = {}  # most recently *folded* metrics, host-side
         self._t0 = time.perf_counter()
 
     def update(self, metrics: Dict) -> None:
@@ -123,7 +141,7 @@ class MetricsAccumulator:
             host[k] = float(v)
             self.acc[k] = self.acc.get(k, 0.0) + host[k]
         self.episodes += host.get("episodes", 0.0)
-        self._last = metrics
+        self._last = host
 
     def _drain(self) -> None:
         for metrics in self._pending:
@@ -240,9 +258,10 @@ class ParallelRL:
             self._collect_host = collect_host
             self._act = make_host_act_step(agent.act_fn())
             # one reusable trajectory staging set: the synchronous loop fully
-            # consumes each update (MetricsAccumulator blocks on the metric
-            # scalars) before the next rollout overwrites the buffers, so a
-            # single set is race-free — zero numpy allocation per iteration
+            # consumes each update (``run`` folds this path's metric scalars
+            # at once, not one update late) before the next rollout
+            # overwrites the buffers, so a single set is race-free — zero
+            # numpy allocation per iteration
             self._staging = StagingSet(agent.hp.t_max, env.n_envs,
                                        env.obs_shape, env.obs_dtype)
             # shared with the pipelined learner: same jitted update step,
@@ -335,13 +354,30 @@ class ParallelRL:
         return metrics
 
     def run(self, iterations: int, log_every: int = 0) -> RunResult:
-        """Run `iterations` framework iterations (each = n_e·t_max timesteps)."""
+        """Run `iterations` framework iterations (each = n_e·t_max timesteps).
+
+        On the fused path each update's metrics are folded one update late:
+        after dispatching update k the host starts the copy of k's scalars
+        and folds update k−1's, so at most two updates are in flight and
+        the host only blocks on an update with the next one queued behind
+        it. The last update is folded after the loop, before the clock is
+        read. The ``HostEnvPool`` path folds each update at once: its next
+        collect reuses the staging buffers the update reads.
+        """
         # fresh telemetry hub per run, kept on self like PipelinedRL's
         self.telemetry = Telemetry()
         spans = self.telemetry.emitter("driver")
         acc = MetricsAccumulator()
+
+        def read(metrics, deferred: bool) -> None:
+            spans.begin(METRICS_READ)
+            if deferred and not MetricsAccumulator._ready(metrics):
+                self.telemetry.counter_add(READS_WAITED, 1)
+            acc.update(metrics)
+            spans.end()
+
         step_arr = jnp.asarray(self.total_steps, jnp.int32)
-        batch = None
+        batch = previous = None
         for i in range(iterations):
             with step_annotation("train",
                                  self.total_steps // self._steps_per_iter):
@@ -350,18 +386,29 @@ class ParallelRL:
                 spans.begin(STEP_DISPATCH)
                 metrics = self._dispatch(step_arr, batch)
                 step_arr = step_arr + 1
+                if not self._host:
+                    for v in metrics.values():
+                        if isinstance(v, jax.Array):
+                            v.copy_to_host_async()
                 spans.end()
                 self.total_steps += self._steps_per_iter
-                spans.begin(METRICS_READ)
-                acc.update(metrics)
-                spans.end()
+                if self._host:
+                    read(metrics, deferred=False)
+                else:
+                    if previous is not None:
+                        read(previous, deferred=True)
+                    previous = metrics
             if log_every and (i + 1) % log_every == 0:
+                # the folded updates only: no read of the one in flight
                 log.info(
                     "iter %d steps %d reward_sum %.3f loss %.4f",
                     i + 1, self.total_steps,
-                    acc.acc.get("reward_sum", 0.0),
-                    float(metrics.get("loss", 0.0)),
+                    acc.acc.get("reward_sum", 0.0), acc.last("loss"),
                 )
+        if previous is not None:
+            read(previous, deferred=True)
+        waited = int(self.telemetry.counter(READS_WAITED))
         return acc.result(self.total_steps, self._steps_per_iter,
                           dispatch_s=spans.total(STEP_DISPATCH),
-                          readback_s=spans.total(METRICS_READ))
+                          readback_s=spans.total(METRICS_READ),
+                          reads_waited=waited)

@@ -82,11 +82,13 @@ __all__ = [
 # three fault.* stages are the supervisor's recovery episodes (detect a
 # replica failure, respawn it, or give up and degrade). The synchronous
 # driver's (``ParallelRL.run``) two stages: step.dispatch (the jitted
-# train step's call and the step counter's increment) and metrics.read
-# (the host converting the update's metric scalars, which waits for the
-# update to finish). Appended, never reordered: shipped worker rings carry
-# category *indices*, so existing entries must keep their positions
-# across versions.
+# train step's call, the step counter's increment and the start of the
+# metrics' host copy) and metrics.read (the host converting an update's
+# metric scalars, which waits for that update to finish: on the fused
+# path the previous update's, read after the next is dispatched).
+# Appended, never reordered: shipped worker rings carry category
+# *indices*, so existing entries must keep their positions across
+# versions.
 CATEGORIES: Tuple[str, ...] = (
     "collect",
     "queue.put_wait",

@@ -4,11 +4,12 @@ profiler mirroring, and the train step's named scopes.
 Pins:
 
 * ``ParallelRL.run`` records one ``step.dispatch`` and one
-  ``metrics.read`` span per update; under ``jax.profiler`` each lands in
-  the profile's host plane, with durations equal to the emitter's totals
-  (``RunResult.dispatch_s`` / ``readback_s``) within 2% — the spans and
-  the device trace share one clock — beside one ``train`` step annotation
-  per update,
+  ``metrics.read`` span per update (the read folds the previous update's
+  scalars after the next dispatch, the last update's after the loop);
+  under ``jax.profiler`` each lands in the profile's host plane, with
+  durations equal to the emitter's totals (``RunResult.dispatch_s`` /
+  ``readback_s``) within 2% — the spans and the device trace share one
+  clock — beside one ``train`` step annotation per update,
 * with the profiler off no ``TraceAnnotation`` is built, and the new
   ``RunResult`` fields are still filled (``host_reads``: one transfer per
   device metric scalar, each read once),
