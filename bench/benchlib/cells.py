@@ -5,12 +5,16 @@ metrics. Everything that belongs to one of them is a file of its own under
 ``bench/``, found by the name alone:
 
 * ``workloads/<cell>.json``  the cell: configuration, entry, sizes, limits;
-* ``configs/<config>.json``  the configuration's published widths;
+* ``configs/<config>.json``  the configuration's published widths, and the
+  model family it belongs to (its ``family`` key);
+* ``families/<family>.py``   the family: the program's job, its FLOP count
+  and its plain reference (``job``, ``flops_per_timestep``, ``train`` and
+  ``FAULTS``);
 * ``entries/<entry>.py``     how to build and drive one entry of the program;
 * ``metrics/<metric>.py``    the reader of one metric.
 
-Adding a cell, a configuration, an entry kind or a metric adds a file and
-edits none.
+Adding a cell, a configuration, a model family, an entry kind or a metric
+adds a file and edits none.
 """
 from __future__ import annotations
 
@@ -30,6 +34,7 @@ class Cell(NamedTuple):
     name: str
     workload: dict
     config: dict
+    family: ModuleType
     entry: ModuleType
 
 
@@ -58,11 +63,24 @@ def spec() -> dict:
     return load_json(ROOT / "BENCHMARK.json")
 
 
+def family(name: str) -> ModuleType:
+    """The model family ``name``: ``families/<name>.py``."""
+    return load_module(BENCH / "families" / f"{_checked(name)}.py")
+
+
 def load_cell(name: str) -> Cell:
     workload = load_json(BENCH / "workloads" / f"{_checked(name)}.json")
-    config = load_json(BENCH / "configs" / f"{_checked(workload['config'])}.json")
+    path = BENCH / "configs" / f"{_checked(workload['config'])}.json"
+    config = load_json(path)
+    if "family" not in config:
+        raise ValueError(f"{path} names no model family (its 'family' key)")
+    try:
+        fam = family(config["family"])
+    except FileNotFoundError as e:
+        raise ValueError(f"{path}: family {config['family']!r} has no "
+                         f"module {e}") from None
     entry = load_module(BENCH / "entries" / f"{_checked(workload['entry'])}.py")
-    return Cell(name, workload, config, entry)
+    return Cell(name, workload, config, fam, entry)
 
 
 def reader(metric: str) -> ModuleType:

@@ -2,13 +2,19 @@
 
 The program is driven from the seed through its first three updates, by
 the same call and on the same object that the measured window then uses.
-The plain reference (``bench/reference``) follows the same three updates
-from the same seed. Three numbers are compared, each against a limit the
-cell's file states:
+The plain reference (the configuration's family's ``train``) follows the
+same three updates from the same seed. Three numbers are compared, each
+against a limit the cell's file states:
 
 ``loss_gap``
-    The largest relative gap over the three updates between the program's
-    loss and the reference's: |L_p - L_r| / |L_r|.
+    The relative gap between the program's loss and the reference's in the
+    first update, |L_p - L_r| / |L_r| (infinite where any of the
+    program's losses is not finite). The later updates' losses are not
+    compared: from the second update on, the two sides act with parameters
+    that differ in rounding, a few categorical draws flip, the games go on
+    differently, and the relative gap of those losses swings from 1e-5 to
+    0.9 from seed to seed on a sound program on a TPU v5e. The later
+    updates show in ``change_gap``.
 ``grad_gap``
     The first gradient as the optimizer got it (after clipping). The
     program's is read back from RMSProp's accumulator after one update,
@@ -68,13 +74,13 @@ def program_grad_norms(sq_after_one, decay: float) -> Dict[str, float]:
 
 def readings(prog: dict, ref: dict, decay: float) -> Dict[str, float]:
     """prog: ``losses``, ``sq1``, ``params0``, ``params``; ref: the dict
-    ``reference.paac.train`` returns. Returns the three numbers."""
+    the family's reference ``train`` returns. Returns the three numbers."""
     if len(prog["losses"]) != len(ref["losses"]):
         raise ValueError("program and reference ran different step counts")
-    loss_gap = 0.0
-    for lp, lr in zip(prog["losses"], ref["losses"]):
-        gap = abs(lp - lr) / abs(lr) if lr != 0 else abs(lp)
-        loss_gap = max(loss_gap, gap if math.isfinite(lp) else math.inf)
+    lp, lr = prog["losses"][0], ref["losses"][0]
+    loss_gap = abs(lp - lr) / abs(lr) if lr != 0 else abs(lp)
+    if not all(math.isfinite(x) for x in prog["losses"]):
+        loss_gap = math.inf
 
     g_prog = program_grad_norms(prog["sq1"], decay)
     g_ref = _norms(ref["grads"])

@@ -18,8 +18,9 @@ In order, in one process:
    start to the window's start. With ``--trace 1`` the window runs under
    the JAX profiler inside a ``bench.window`` annotation.
 3. After the window: read the chips' peak memory, free the program's state,
-   run the plain reference over the same three updates and compare (see
-   ``benchlib.check``). Then each metric's reader.
+   run the configuration's family's plain reference over the same three
+   updates and compare (see ``benchlib.check``). Then each metric's reader;
+   ``mfu`` counts its FLOPs by the family.
 
 The last line of standard output is the result as one JSON object; the
 numbers compared, each beside its limit, are the last lines of standard
@@ -41,7 +42,7 @@ import traceback
 from types import SimpleNamespace
 from typing import Callable, Dict, List, Optional
 
-from benchlib import cells, chip, check, flops
+from benchlib import cells, chip, check
 from benchlib import trace as tr
 
 CHECKED_UPDATES = 3
@@ -134,11 +135,10 @@ def reference_readings(cell, seed: int, prog: dict, dtype=None,
     """The three numbers, the program's ``prog`` against the reference (or,
     with ``dtype``/``fault``, against a control or a planted fault)."""
     import jax.numpy as jnp
-    from reference import paac
 
-    ref = paac.train(cell.config, seed, steps=len(prog["losses"]),
-                     dtype=dtype or jnp.float32, fault=fault,
-                     **cell.entry.reference_layout(cell.workload))
+    ref = cell.family.train(cell.config, seed, steps=len(prog["losses"]),
+                            dtype=dtype or jnp.float32, fault=fault,
+                            **cell.entry.reference_layout(cell.workload))
     return check.readings(prog, ref, cell.config["optimizer"]["decay"])
 
 
@@ -148,10 +148,10 @@ def reference_as_program(cell, seed: int, dtype=None,
     would have read from it."""
     import jax
     import jax.numpy as jnp
-    from reference import paac
 
-    run = paac.train(cell.config, seed, dtype=dtype or jnp.float32,
-                     fault=fault, **cell.entry.reference_layout(cell.workload))
+    run = cell.family.train(cell.config, seed, dtype=dtype or jnp.float32,
+                            fault=fault,
+                            **cell.entry.reference_layout(cell.workload))
     decay = cell.config["optimizer"]["decay"]
     sq1 = jax.tree_util.tree_map(
         lambda g: (1.0 - decay) * jnp.square(g.astype(jnp.float32)),
@@ -229,7 +229,8 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, devices,
         setup_s=setup_s, window_s=window_s, updates=n, timesteps=timesteps,
         learner_idle_s=learner_idle_s, chips=len(devices), config=config,
         workload=workload, device_kind=devices[0].device_kind,
-        flops_per_timestep=flops.flops_per_timestep(config, workload["t_max"]),
+        flops_per_timestep=cell.family.flops_per_timestep(
+            config, workload["t_max"]),
         learner_queue=getattr(cell.entry, "LEARNER_QUEUE", False),
         trace=None, lo=None, hi=None, busy_s=None, trace_window_s=None)
     breakdown = None

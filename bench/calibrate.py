@@ -7,12 +7,12 @@ For each ``--seeds`` seed: the program, built and driven exactly as a run's
 set-up drives it (three ``run(1)`` updates), against the plain reference:
 the lower readings. For each ``--control-seeds`` seed: the reference in the
 next precision below the configuration's (bfloat16) put in the program's
-place, and each fault the cell can have planted in the reference put in
-the program's place (half of the batch; on a cell of several lanes, one
-lane's batch alone, as without the exchange between chips; every sampled
-action altered where it is drawn); a state left unchanged reads 1 on
-``change_gap`` and is computed the same way. These are the upper readings.
-Not part of a benchmark run.
+place, and each fault of the family's ``FAULTS`` that the cell can have
+planted in the reference put in the program's place (half of the batch;
+on a cell of several lanes, one lane's batch alone, as without the
+exchange between chips; every sampled action altered where it is drawn);
+a state left unchanged reads 1 on ``change_gap`` and is computed the same
+way. These are the upper readings. Not part of a benchmark run.
 """
 import time
 
@@ -57,9 +57,9 @@ def main(argv=None) -> int:
         print(f"program seed {seed}: {out['program'][seed]} "
               f"({time.perf_counter() - t0:.1f} s)", file=sys.stderr,
               flush=True)
-    faults = ["half_batch", "altered_action"]
-    if cell.workload.get("lanes", 1) > 1:
-        faults.append("one_lane")
+    # one lane's batch alone is the exchange left out: only across lanes
+    faults = [f for f in cell.family.FAULTS
+              if f != "one_lane" or cell.workload.get("lanes", 1) > 1]
     for seed in args.control_seeds:
         ctrl = harness.reference_as_program(cell, seed, dtype=jnp.bfloat16)
         out["control"][seed] = harness.reference_readings(cell, seed, ctrl)

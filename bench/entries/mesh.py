@@ -7,19 +7,20 @@ over the chips, and the sharded learner step all-reduces the per-chip
 gradients. Depth-1 lockstep with infinite V-trace clips: every update
 learns from rollouts acted with the parameters it updates (on-policy,
 staleness 0). One ``run(n)`` is n learner updates; the cell's measured
-window is one such call.
+window is one such call. Each lane's environment, and the agent, are the
+configuration's family's ``job``.
 """
 from __future__ import annotations
 
-from benchlib.paper_job import paper_job
+from benchlib import cells
 
 # the learner waits on a trajectory queue (RunResult.learner_idle_s)
 LEARNER_QUEUE = True
 
 
 def reference_layout(workload: dict) -> dict:
-    """How ``reference.paac.train`` follows this entry: every ``run()``
-    call draws one acting key per lane from the carried key."""
+    """How the family's reference ``train`` follows this entry: every
+    ``run()`` call draws one acting key per lane from the carried key."""
     return {"n_envs": workload["n_envs"], "lanes": workload["lanes"],
             "t_max": workload["t_max"], "lr": workload["lr"],
             "lane_keys_per_step": True}
@@ -35,7 +36,8 @@ class Entry:
         if lanes != len(devices):
             raise ValueError(f"{lanes} lanes need {lanes} chips, "
                              f"got {len(devices)}")
-        jobs = [paper_job(config, workload["n_envs"], workload["t_max"])
+        job = cells.family(config["family"]).job
+        jobs = [job(config, workload["n_envs"], workload["t_max"])
                 for _ in range(lanes)]
         agent, self.settings = jobs[0][1], jobs[0][2]
         inf = float("inf")

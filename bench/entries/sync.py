@@ -3,15 +3,16 @@
 One ``run(n)`` is n framework iterations, each one compiled program that
 acts for every environment for t_max steps, computes the returns and
 applies one RMSProp update. The cell's measured window is one such call.
+The environment and agent are the configuration's family's ``job``.
 """
 from __future__ import annotations
 
-from benchlib.paper_job import paper_job
+from benchlib import cells
 
 
 def reference_layout(workload: dict) -> dict:
-    """How ``reference.paac.train`` follows this entry: one lane that
-    carries its acting key from update to update."""
+    """How the family's reference ``train`` follows this entry: one lane
+    that carries its acting key from update to update."""
     return {"n_envs": workload["n_envs"], "lanes": 1,
             "t_max": workload["t_max"], "lr": workload["lr"],
             "lane_keys_per_step": False}
@@ -22,8 +23,9 @@ class Entry:
         from repro.core import ParallelRL
         from repro.optim import constant
 
-        env, agent, self.settings = paper_job(config, workload["n_envs"],
-                                              workload["t_max"])
+        job = cells.family(config["family"]).job
+        env, agent, self.settings = job(config, workload["n_envs"],
+                                        workload["t_max"])
         self.timesteps_per_update = workload["n_envs"] * workload["t_max"]
         self.rl = ParallelRL(env, agent, optimizer="rmsprop",
                              lr_schedule=constant(workload["lr"]), seed=seed)

@@ -1,6 +1,6 @@
 """Layer ``model``: model FLOP utilisation of the whole step. The FLOPs the
-network's forward and backward passes require per timestep (counted from
-the configuration's widths by ``benchlib.flops``; recomputation excluded),
+network's forward and backward passes require per timestep (counted by the
+configuration's family, ``families/<family>.py``; recomputation excluded),
 times the timesteps of the traced window, over the traced window's length
 times the chips times one chip's bf16 peak from ``bench/peaks.json``.
 Moves timesteps_per_s."""

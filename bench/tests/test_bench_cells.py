@@ -28,6 +28,8 @@ def test_each_cell_has_its_files(name):
                                             "change_gap"}
     assert hasattr(cell.entry, "Entry")
     assert hasattr(cell.entry, "reference_layout")
+    for name in ("job", "flops_per_timestep", "train", "FAULTS"):
+        assert hasattr(cell.family, name), name
 
 
 @pytest.mark.parametrize("kind, metric", METRICS,
@@ -46,24 +48,40 @@ def test_each_metric_has_a_reader_that_agrees(kind, metric):
 @pytest.mark.parametrize("config", SPEC["configs"],
                          ids=[c["name"] for c in SPEC["configs"]])
 def test_each_configuration_file_is_what_the_program_runs(config):
-    from benchlib.paper_job import paper_job
-
     data = cells.load_json(cells.ROOT / config["file"])
     assert data["name"] == config["name"]
     assert data["source"] == config["source"]
     assert data["reduced"] == config["reduced"]
-    _, _, settings = paper_job(data, n_envs=2, t_max=5)
+    _, _, settings = cells.family(data["family"]).job(data, n_envs=2, t_max=5)
     harness.guard_widths(settings, data)
 
 
 def test_a_width_the_program_does_not_run_is_refused():
     data = cells.load_json(cells.BENCH / "configs" / "paac_nature.json")
-    from benchlib.paper_job import paper_job
-
-    _, _, settings = paper_job(data, n_envs=2, t_max=5)
+    _, _, settings = cells.family(data["family"]).job(data, n_envs=2, t_max=5)
     wrong = dict(data, dense=256)
     with pytest.raises(ValueError, match="dense"):
         harness.guard_widths(settings, wrong)
+
+
+@pytest.mark.parametrize("family", [None, "no_such_family"])
+def test_a_configuration_without_a_family_module_is_refused(
+        family, tmp_path, monkeypatch):
+    """No default family: a configuration that names none, or one that has
+    no file, is refused with the configuration file's name."""
+    config = cells.load_json(cells.BENCH / "configs" / "paac_nature.json")
+    config.pop("family")
+    if family is not None:
+        config["family"] = family
+    workload = dict(cells.load_cell("nature-sync-e32").workload,
+                    config="orphan")
+    for sub, name, data in (("configs", "orphan", config),
+                            ("workloads", "orphan-cell", workload)):
+        (tmp_path / sub).mkdir()
+        (tmp_path / sub / f"{name}.json").write_text(json.dumps(data))
+    monkeypatch.setattr(cells, "BENCH", tmp_path)
+    with pytest.raises(ValueError, match=r"configs/orphan\.json"):
+        cells.load_cell("orphan-cell")
 
 
 def test_metrics_for_a_cell():

@@ -126,3 +126,32 @@ def test_a_nan_loss_is_never_within_a_limit():
                               "change_gap": 0.0},
                              {"loss_gap": 1.0, "grad_gap": 1.0,
                               "change_gap": 1.0})
+
+
+def _readings(prog_losses, ref_losses):
+    import numpy as np
+
+    decay = 0.99
+    grads = {"w": np.array([0.5, -0.5], np.float32)}
+    before = {"w": np.zeros(2, np.float32)}
+    after = {"w": np.ones(2, np.float32)}
+    prog = {"losses": prog_losses, "params0": before, "params": after,
+            "sq1": {"w": (1.0 - decay) * np.square(grads["w"])}}
+    ref = {"losses": ref_losses, "grads": grads, "params0": before,
+           "params": after}
+    return check.readings(prog, ref, decay)
+
+
+def test_loss_gap_is_the_first_updates():
+    """The later updates learn from rollouts that have parted: only the
+    first update's loss is compared."""
+    assert _readings([2.0, 5.0, -3.0], [2.0, 1.0, 1.0])["loss_gap"] == 0.0
+    assert _readings([2.2, 1.0, 1.0], [2.0, 1.0, 1.0])["loss_gap"] == \
+        pytest.approx(0.1)
+
+
+def test_a_later_loss_that_is_not_finite_fails():
+    numbers = _readings([2.0, math.nan, 1.0], [2.0, 1.0, 1.0])
+    assert numbers["loss_gap"] == math.inf
+    assert not check.verdict(numbers, {"loss_gap": 1.0, "grad_gap": 1.0,
+                                       "change_gap": 1.0})
