@@ -13,6 +13,7 @@ from repro.configs import (  # noqa: F401
     dbrx_132b,
     deepseek_coder_33b,
     deepseek_v2_236b,
+    deepseek_v2_lite,
     glm4_9b,
     mamba2_370m,
     minicpm3_4b,

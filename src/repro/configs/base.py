@@ -335,6 +335,14 @@ class ArchConfig:
     head_dim: int = 64
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    # YaRN rotary scaling (DeepSeek-V2's ``rope_scaling`` of type "yarn");
+    # yarn_factor 0 -> plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # MLA (DeepSeek-V2 / MiniCPM3 style multi-head latent attention)
     q_lora_rank: int = 0
@@ -358,6 +366,15 @@ class ArchConfig:
     router_aux_coef: float = 0.01
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 4096  # routing group (sequence chunk) length
+    # expert parallelism's share: this chip holds experts
+    # [experts_held_from, experts_held_from + experts_held) of the
+    # num_experts the router scores, and computes their part of the layer
+    # with no drops (models.moe.moe_held_forward); 0 -> the capacity path
+    # over all num_experts
+    experts_held: int = 0
+    experts_held_from: int = 0
+    norm_topk_prob: bool = True  # renormalise the top-k routing weights
+    routed_scaling_factor: float = 1.0
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -445,6 +462,8 @@ class ArchConfig:
                 first_dense_layers=min(self.first_dense_layers, 1),
                 dense_d_ff=min(self.dense_d_ff, 256) if self.dense_d_ff else 0,
             )
+            if self.experts_held:
+                kw.update(experts_held=kw["num_experts"], experts_held_from=0)
         if self.ssm_state:
             kw.update(ssm_state=min(self.ssm_state, 16), ssm_chunk=32)
         if self.shared_attn_every:

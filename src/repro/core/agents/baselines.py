@@ -23,10 +23,14 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.agents.paac import PAACAgent, PAACConfig, paac_losses
+from repro.core.agents.paac import (
+    PAACAgent,
+    PAACConfig,
+    paac_losses,
+    trajectory_logits_values,
+)
 from repro.core.returns import n_step_returns
 from repro.core.rollout import rollout
-from repro.models import policy_apply
 
 
 class LaggedConfig(NamedTuple):
@@ -60,12 +64,7 @@ class LaggedPAACAgent(PAACAgent):
 
         def loss_fn(params, traj, bootstrap):
             T, E = traj.action.shape
-            obs = traj.obs.reshape((T * E,) + traj.obs.shape[2:])
-            if cfg.family == "cnn":
-                logits, values, _ = policy_apply(params, cfg, obs)
-            else:
-                lg, vl, _ = policy_apply(params, cfg, obs)
-                logits, values = lg[:, -1], vl[:, -1]
+            logits, values, _ = trajectory_logits_values(params, cfg, traj)
             returns = n_step_returns(traj.reward.T, traj.done.T, bootstrap, hp.gamma)
             return paac_losses(
                 logits,

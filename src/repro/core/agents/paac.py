@@ -28,7 +28,13 @@ import jax.numpy as jnp
 from repro.core.agents.base import Agent
 from repro.core.returns import n_step_returns
 from repro.core.rollout import rollout
-from repro.models import policy_apply
+from repro.models import policy_apply, policy_apply_last
+
+
+# the train step's metric that counts work (``ParallelRL.run`` publishes its
+# run total): assignments to held experts in the learner's trajectory pass,
+# summed over layers
+HELD_LEARNER = "count.moe_held_learner"
 
 
 class PAACConfig(NamedTuple):
@@ -66,21 +72,25 @@ def paac_losses(logits, values, actions, returns, beta, value_coef,
     }
 
 
+def policy_heads(params, cfg, obs, *, train: bool = False):
+    """``(logits (B, A), values (B,), aux)``: CNN policies over the whole
+    observation, token policies at each context's last position only."""
+    if cfg.family == "cnn":
+        return policy_apply(params, cfg, obs)
+    return policy_apply_last(params, cfg, obs, train=train)
+
+
 def trajectory_logits_values(params, cfg, traj):
     """One batched learning-pass forward over a time-major ``Transition``.
 
-    Returns ``(logits (N, A), values (N,))`` flattened time-major to the
-    n_e·t_max batch (index = t·n_e + e). The pipelined V-trace learner uses
-    this directly (it builds its own targets from the importance ratios).
+    Returns ``(logits (N, A), values (N,), aux)`` flattened time-major to
+    the n_e·t_max batch (index = t·n_e + e), with the model's aux dict. The
+    pipelined V-trace learner uses this directly (it builds its own targets
+    from the importance ratios).
     """
     T, E = traj.action.shape
     obs = traj.obs.reshape((T * E,) + traj.obs.shape[2:])
-    if cfg.family == "cnn":
-        logits, values, _ = policy_apply(params, cfg, obs)
-    else:
-        lg, vl, _ = policy_apply(params, cfg, obs)
-        logits, values = lg[:, -1], vl[:, -1]
-    return logits, values
+    return policy_heads(params, cfg, obs, train=True)
 
 
 def trajectory_forward(params, cfg, hp, traj, bootstrap):
@@ -88,17 +98,18 @@ def trajectory_forward(params, cfg, hp, traj, bootstrap):
 
     Shared by the fused synchronous train step and the pipelined learner
     (``repro.pipeline.learner``) so the two backends optimize the same
-    quantities. Returns ``(logits, values, actions, returns)`` flattened to
-    the n_e·t_max batch the paper's equations average over.
+    quantities. Returns ``(logits, values, actions, returns, aux)``
+    flattened to the n_e·t_max batch the paper's equations average over,
+    with the model's aux dict.
     """
     T, E = traj.action.shape
-    logits, values = trajectory_logits_values(params, cfg, traj)
+    logits, values, aux = trajectory_logits_values(params, cfg, traj)
     returns = n_step_returns(
         traj.reward.T, traj.done.T, bootstrap, hp.gamma
     )  # (E, T)
     returns = returns.T.reshape(T * E)
     actions = traj.action.reshape(T * E)
-    return logits, values, actions, returns
+    return logits, values, actions, returns, aux
 
 
 def learn(act, loss_fn, optimizer, lr_schedule, params, opt_state, traj,
@@ -142,12 +153,8 @@ class PAACAgent(Agent):
         cfg = self.cfg
 
         def fn(params, obs):
-            if cfg.family == "cnn":
-                logits, value, _ = policy_apply(params, cfg, obs)
-                return logits, value
-            # token policies: obs is the token context; act on last position
-            logits, values, _ = policy_apply(params, cfg, obs)
-            return logits[:, -1], values[:, -1]
+            logits, value, _ = policy_heads(params, cfg, obs)
+            return logits, value
 
         return fn
 
@@ -159,12 +166,15 @@ class PAACAgent(Agent):
         def loss_fn(params, traj, bootstrap):
             # recompute forward over the whole n_e·t_max batch (one big
             # batched pass — the paper's batched learning)
-            logits, values, actions, returns = trajectory_forward(
+            logits, values, actions, returns, aux = trajectory_forward(
                 params, cfg, hp, traj, bootstrap
             )
-            return paac_losses(
+            loss, metrics = paac_losses(
                 logits, values, actions, returns, hp.entropy_beta, hp.value_coef
             )
+            if "moe_held" in aux:
+                metrics[HELD_LEARNER] = aux["moe_held"].astype(jnp.float32)
+            return loss, metrics
 
         def train_step(params, opt_state, env_state, obs, key, step):
             env_state, last_obs, key, traj = rollout(

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from repro.core.agents.base import Agent
 from repro.core.returns import gae_advantages
 from repro.core.rollout import rollout
-from repro.models import policy_apply
+from repro.core.agents.paac import policy_heads, trajectory_logits_values
 
 
 class PPOConfig(NamedTuple):
@@ -40,9 +40,7 @@ class PPOAgent(Agent):
         cfg = self.cfg
 
         def fn(params, obs):
-            logits, value, _ = policy_apply(params, cfg, obs)
-            if cfg.family != "cnn":
-                logits, value = logits[:, -1], value[:, -1]
+            logits, value, _ = policy_heads(params, cfg, obs)
             return logits, value
 
         return fn
@@ -53,10 +51,7 @@ class PPOAgent(Agent):
 
         def loss_fn(params, traj, adv, returns):
             T, E = traj.action.shape
-            obs = traj.obs.reshape((T * E,) + traj.obs.shape[2:])
-            logits, values, _ = policy_apply(params, cfg, obs)
-            if cfg.family != "cnn":
-                logits, values = logits[:, -1], values[:, -1]
+            logits, values, _ = trajectory_logits_values(params, cfg, traj)
             logp_all = jax.nn.log_softmax(logits)
             actions = traj.action.reshape(T * E)
             logp = jnp.take_along_axis(logp_all, actions[:, None], 1)[:, 0]

@@ -61,6 +61,10 @@ log = get_logger("framework")
 
 # hub counter of the sync driver's deferred folds that waited on the device
 READS_WAITED = "reads_waited"
+# train-step metrics that count work (summed over a run, not averaged in
+# meaning) and the monitoring event each run's total is published under
+COUNTER_PREFIX = "count."
+RUN_COUNTER_EVENT = "/repro/run/"
 
 
 @dataclass
@@ -408,7 +412,18 @@ class ParallelRL:
         if previous is not None:
             read(previous, deferred=True)
         waited = int(self.telemetry.counter(READS_WAITED))
-        return acc.result(self.total_steps, self._steps_per_iter,
-                          dispatch_s=spans.total(STEP_DISPATCH),
-                          readback_s=spans.total(METRICS_READ),
-                          reads_waited=waited)
+        result = acc.result(self.total_steps, self._steps_per_iter,
+                            dispatch_s=spans.total(STEP_DISPATCH),
+                            readback_s=spans.total(METRICS_READ),
+                            reads_waited=waited)
+        publish_counters(acc.acc)
+        return result
+
+
+def publish_counters(totals: Dict[str, float]) -> None:
+    """Hand each counter's run total (metrics named ``count.*``) to JAX's
+    monitoring listeners as the scalar ``/repro/run/<name>``, so that an
+    observer of the process reads them without the ``RunResult``."""
+    for name, total in totals.items():
+        if name.startswith(COUNTER_PREFIX):
+            jax.monitoring.record_scalar(RUN_COUNTER_EVENT + name, total)

@@ -5,6 +5,8 @@ exposes the same functional surface consumed by the PAAC core and launchers:
 
 * ``init_policy(key, cfg)``                    -> params
 * ``policy_apply(params, cfg, obs/tokens, …)`` -> (logits, values) full pass
+* ``policy_apply_last(params, cfg, tokens, …)`` -> the heads at the last
+  position only (a token policy's action and value)
 * ``init_policy_cache(cfg, batch, max_len)``   -> decode cache (token models)
 * ``policy_decode(params, cfg, cache, tok, pos)`` -> (logits, value, cache)
 * ``policy_prefill(params, cfg, tokens, …)``   -> (logits, value, cache)
@@ -46,6 +48,17 @@ def policy_apply(params, cfg, obs, prefix_embeds=None, *, train: bool = False,
                               train=train, window=window)
     embed = params["trunk"]["embed"] if cfg.tie_policy_head else None
     logits, values = apply_heads(params["heads"], cfg, hidden, embed)
+    return logits, values, aux
+
+
+def policy_apply_last(params, cfg, tokens, *, train: bool = False):
+    """Token policies: the trunk over the whole context, the heads on its
+    last position only. tokens (B, S) -> (logits (B, A), values (B,), aux)
+    — the last row of ``policy_apply``'s per-position outputs, without the
+    other S-1 rows' policy logits."""
+    hidden, aux = tfm.forward(params["trunk"], cfg, tokens, train=train)
+    embed = params["trunk"]["embed"] if cfg.tie_policy_head else None
+    logits, values = apply_heads(params["heads"], cfg, hidden[:, -1], embed)
     return logits, values, aux
 
 

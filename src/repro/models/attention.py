@@ -36,6 +36,8 @@ from repro.models.common import (
     init_rmsnorm,
     linear,
     rmsnorm,
+    rope_for,
+    softmax_scale_for,
     split_keys,
 )
 
@@ -413,19 +415,27 @@ def _mla_latent(p, cfg, x):
 
 def mla_forward(p, cfg, x, *, positions=None, window: int = 0, block_k: int = 512,
                 return_cache: bool = False):
-    """Full-sequence MLA (train / prefill)."""
+    """Full-sequence MLA (train / prefill), under the ``mla`` name scope.
+    Rotary embedding and softmax scale follow ``cfg`` (YaRN where set)."""
+    with jax.named_scope("mla"):
+        return _mla_forward(p, cfg, x, positions, window, block_k, return_cache)
+
+
+def _mla_forward(p, cfg, x, positions, window, block_k, return_cache):
     B, S, _ = x.shape
     H = cfg.num_heads
     pos = positions if positions is not None else jnp.arange(S)
+    inv_freq, rope_mscale = rope_for(cfg, cfg.qk_rope_dim)
     q_nope, q_rope = _mla_queries(p, cfg, x)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, pos, cfg.rope_theta, inv_freq, rope_mscale)
     c, k_rope = _mla_latent(p, cfg, x)
-    k_rope = apply_rope(k_rope[:, :, None, :], pos, cfg.rope_theta)  # (B,S,1,rope)
+    k_rope = apply_rope(k_rope[:, :, None, :], pos, cfg.rope_theta, inv_freq,
+                        rope_mscale)  # (B,S,1,rope)
     kv = linear(p["wukv"], c).reshape(B, S, H, cfg.qk_nope_dim + cfg.v_head_dim)
     k_nope, v = jnp.split(kv, [cfg.qk_nope_dim], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H, cfg.qk_rope_dim))], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = softmax_scale_for(cfg, cfg.qk_nope_dim + cfg.qk_rope_dim)
     out = chunked_attention(q, k, v, causal=True, window=window, block_k=block_k,
                             scale=scale, seq_shard_mode=cfg.attn_seq_shard)
     y = linear(p["wo"], out.reshape(B, S, H * cfg.v_head_dim))
@@ -449,8 +459,14 @@ def mla_decode(p, cfg, x, cache, pos, *, window: int = 0):
     W_uv into the output) versus the naive path that reconstructs all
     per-head K/V from the latent every step. ``pos`` is a scalar (shared
     position) or a ``(B,)`` vector of per-row positions (serving plane) —
-    see ``gqa_decode``; both modes are row-independent.
+    see ``gqa_decode``; both modes are row-independent. Runs under the
+    ``mla`` name scope.
     """
+    with jax.named_scope("mla"):
+        return _mla_decode(p, cfg, x, cache, pos, window)
+
+
+def _mla_decode(p, cfg, x, cache, pos, window):
     B = x.shape[0]
     H = cfg.num_heads
     slots = cache["c"].shape[1]
@@ -458,11 +474,14 @@ def mla_decode(p, cfg, x, cache, pos, *, window: int = 0):
     win = window if window else slots
     q_nope, q_rope = _mla_queries(p, cfg, x)  # (B,1,H,*)
     c_new, kr_new = _mla_latent(p, cfg, x)  # (B,1,kv_lora), (B,1,rope)
+    inv_freq, rope_mscale = rope_for(cfg, cfg.qk_rope_dim)
 
     if jnp.ndim(pos) == 0:
         pos_arr = jnp.full((1,), pos, jnp.int32)
-        q_rope = apply_rope(q_rope, pos_arr, cfg.rope_theta)
-        kr_new = apply_rope(kr_new[:, :, None, :], pos_arr, cfg.rope_theta)[:, :, 0, :]
+        q_rope = apply_rope(q_rope, pos_arr, cfg.rope_theta, inv_freq,
+                            rope_mscale)
+        kr_new = apply_rope(kr_new[:, :, None, :], pos_arr, cfg.rope_theta,
+                            inv_freq, rope_mscale)[:, :, 0, :]
 
         write = pos % slots
         cc = jax.lax.dynamic_update_slice(cache["c"], c_new.astype(cache["c"].dtype), (0, write, 0))
@@ -474,9 +493,10 @@ def mla_decode(p, cfg, x, cache, pos, *, window: int = 0):
             valid = age < jnp.minimum(win, pos + 1)
         maskb = valid[None, None, :]
     else:
-        q_rope = apply_rope(q_rope, pos[:, None], cfg.rope_theta)
+        q_rope = apply_rope(q_rope, pos[:, None], cfg.rope_theta, inv_freq,
+                            rope_mscale)
         kr_new = apply_rope(kr_new[:, :, None, :], pos[:, None],
-                            cfg.rope_theta)[:, :, 0, :]
+                            cfg.rope_theta, inv_freq, rope_mscale)[:, :, 0, :]
 
         write = pos % slots  # (B,)
         hit = slot_idx[None, :] == write[:, None]  # (B, slots)
@@ -489,7 +509,7 @@ def mla_decode(p, cfg, x, cache, pos, *, window: int = 0):
             valid = age < jnp.minimum(win, pos[:, None] + 1)
         maskb = valid[:, None, :]
 
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = softmax_scale_for(cfg, cfg.qk_nope_dim + cfg.qk_rope_dim)
     nope, vdim, rank = cfg.qk_nope_dim, cfg.v_head_dim, cfg.kv_lora_rank
     wukv = p["wukv"]["w"].reshape(rank, H, nope + vdim)
     w_uk, w_uv = wukv[..., :nope], wukv[..., nope:]  # (rank,H,nope),(rank,H,v)

@@ -15,6 +15,13 @@ TPU-native design decisions:
 * Router runs in fp32 (standard practice for MoE numerical stability).
 * Shared experts (DeepSeek-V2) are a plain dense MLP applied to every token.
 * Aux load-balance loss (Switch style) is returned for the training loss.
+* ``moe_held_forward`` is expert parallelism's share of the layer on one
+  chip (``cfg.experts_held``): the router scores all ``num_experts``, the
+  chip computes only the experts it holds, for every assignment routed to
+  them — no capacity, nothing dropped — as grouped matmuls over the
+  assignments sorted by expert (``grouped_matmul``). What the other chips'
+  experts add is not computed here; on one chip the layer runs without
+  the exchange.
 
 Sharding: expert weights are laid out (E, in, out) and sharded E→"model"
 (expert parallel) with the contraction dim sharded over "data" (FSDP); the
@@ -36,21 +43,27 @@ from repro.models.mlp import init_mlp, mlp_forward
 
 
 def init_moe(key, cfg, dtype):
+    """Router over all ``num_experts``; expert weights for the experts held
+    here (``experts_held``, or all of them)."""
     ks = split_keys(key, 5)
     E, d, ff = cfg.num_experts, cfg.d_model, cfg.expert_ff()
+    W = cfg.experts_held or E
     p = {
         "router": {"w": dense_init(ks[0], d, E, jnp.float32)},  # router in fp32
-        "wi": (jax.random.normal(ks[1], (E, d, ff)) / math.sqrt(d)).astype(dtype),
-        "wg": (jax.random.normal(ks[2], (E, d, ff)) / math.sqrt(d)).astype(dtype),
-        "wo": (jax.random.normal(ks[3], (E, ff, d)) / math.sqrt(ff)).astype(dtype),
+        "wi": (jax.random.normal(ks[1], (W, d, ff)) / math.sqrt(d)).astype(dtype),
+        "wg": (jax.random.normal(ks[2], (W, d, ff)) / math.sqrt(d)).astype(dtype),
+        "wo": (jax.random.normal(ks[3], (W, ff, d)) / math.sqrt(ff)).astype(dtype),
     }
     if cfg.num_shared_experts:
         p["shared"] = init_mlp(ks[4], d, ff * cfg.num_shared_experts, dtype, "swiglu")
     return p
 
 
-def _route_group(tokens, router_logits, k: int, capacity: int, E: int):
+def _route_group(tokens, router_logits, k: int, capacity: int, E: int,
+                 norm: bool = True, scale: float = 1.0):
     """Route one group of tokens. tokens: (T, d); logits: (T, E) fp32.
+    ``norm`` renormalises the top-k weights to sum to one; ``scale``
+    multiplies them (DeepSeek's routed_scaling_factor).
 
     Returns (expert_in (E, C, d), slot (T, k), weights (T, k), aux_loss,
     inv_tok (E*C,), w_slot (E*C,)) — inv_tok/w_slot drive the scatter-add
@@ -59,7 +72,10 @@ def _route_group(tokens, router_logits, k: int, capacity: int, E: int):
     T, d = tokens.shape
     probs = jax.nn.softmax(router_logits, axis=-1)  # (T, E)
     top_w, top_e = jax.lax.top_k(probs, k)  # (T, k)
-    top_w = top_w / jnp.maximum(jnp.sum(top_w, axis=-1, keepdims=True), 1e-9)
+    if norm:
+        top_w = top_w / jnp.maximum(jnp.sum(top_w, axis=-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        top_w = top_w * scale
 
     # load-balance aux loss (Switch): E * sum_e f_e * P_e
     assign_frac = jnp.mean(
@@ -120,7 +136,8 @@ def moe_forward(
 
     logits = (xg.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32))  # (G,T,E)
     expert_in, slot, top_w, aux, inv_tok, w_slot = jax.vmap(
-        partial(_route_group, k=k, capacity=capacity, E=E)
+        partial(_route_group, k=k, capacity=capacity, E=E,
+                norm=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor)
     )(xg, logits)
     # expert_in: (G, E, C, d) — constraining E to "model" makes GSPMD lower
     # the dispatch scatter as an all-to-all over the expert-parallel axis
@@ -149,3 +166,93 @@ def moe_forward(
     if "shared" in p:
         y = y + mlp_forward(p["shared"], x)
     return y, jnp.mean(aux)
+
+
+# ---------------------------------------------------------------------------
+# The held experts' share of the layer, dropless
+# ---------------------------------------------------------------------------
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _tile(x: int) -> int:
+    for t in (512, 256, 128):
+        if x % t == 0:
+            return t
+    return x
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    tm = next((t for t in (512, 256, 128, 64, 32, 16, 8) if m % t == 0), m)
+    return tm, _tile(k), _tile(n)
+
+
+def grouped_matmul(x, w, group_sizes):
+    """Rows ``[offset_g, offset_g + group_sizes[g])`` of ``x`` (M, K) times
+    ``w[g]`` (K, N) for each group g, in order; rows past the last group
+    are not computed and hold no defined value. On a TPU the Pallas
+    megablox kernel, which keeps its name scope in the compiled program
+    (XLA's own ragged-dot kernels there carry no ``op_name``, so a trace
+    cannot place their time under ``moe.experts``); on other backends
+    ``lax.ragged_dot``."""
+    if _on_tpu():
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(x, w, group_sizes, x.dtype, _gmm_tiling)
+    return jax.lax.ragged_dot(x, w, group_sizes)
+
+
+def moe_held_forward(p, cfg, x: jnp.ndarray):
+    """This chip's share of an expert layer: (output (B, S, d), held
+    assignments (int32 scalar)).
+
+    The router (fp32) scores all ``cfg.num_experts`` experts; each token
+    takes the greedy top-k of their softmax, renormalised only when
+    ``cfg.norm_topk_prob``, times ``cfg.routed_scaling_factor``. Of the
+    T*k assignments, those to the experts held here (``experts_held``
+    from ``experts_held_from``) are sorted by expert and computed as
+    grouped SwiGLU matmuls, every one of them: the buffer has a row for
+    each of the T*k assignments, so no routing can overflow it, and the
+    matmuls run only over the held rows. The output is the held experts'
+    weighted sum plus the shared experts (whole on every chip).
+    """
+    B, S, d = x.shape
+    T = B * S
+    k, held = cfg.num_experts_per_tok, cfg.experts_held
+    tokens = x.reshape(T, d)
+    with jax.named_scope("moe.route"):
+        logits = tokens.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
+        top_w, top_e = jax.lax.top_k(probs, k)  # greedy top-k, (T, k)
+        if cfg.norm_topk_prob:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        if cfg.routed_scaling_factor != 1.0:
+            top_w = top_w * cfg.routed_scaling_factor
+        local = top_e - cfg.experts_held_from
+        mine = (local >= 0) & (local < held)
+        # held assignments first, by expert; the rest after them
+        group_of = jnp.where(mine, local, held).reshape(T * k)
+        order = jnp.argsort(group_of, stable=True)
+        group_sizes = jnp.bincount(group_of, length=held + 1)[:held]
+        group_sizes = group_sizes.astype(jnp.int32)
+        count = jnp.sum(group_sizes)
+        valid = (jnp.arange(T * k) < count)[:, None]
+        rows = order // k  # the token of each sorted assignment
+    with jax.named_scope("moe.experts"):
+        xs = jnp.where(valid, tokens[rows], 0)
+        h = jax.nn.silu(grouped_matmul(xs, p["wi"], group_sizes))
+        h = h * grouped_matmul(xs, p["wg"], group_sizes)
+        out = jnp.where(valid, grouped_matmul(h, p["wo"], group_sizes), 0)
+    with jax.named_scope("moe.combine"):
+        inv = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        w = jnp.where(mine, top_w, 0.0)  # (T, k), fp32
+        y = jnp.einsum("tk,tkd->td", w, out[inv].reshape(T, k, d),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    y = y.reshape(B, S, d)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + mlp_forward(p["shared"], x)
+    return y, count

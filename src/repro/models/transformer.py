@@ -38,7 +38,7 @@ from repro.models.common import (
     split_keys,
 )
 from repro.models.mlp import init_mlp, mlp_forward
-from repro.models.moe import init_moe, moe_forward
+from repro.models.moe import init_moe, moe_forward, moe_held_forward
 from repro.models.ssm import (
     init_mamba2,
     init_mamba2_cache,
@@ -73,7 +73,9 @@ def init_attn_block(key, cfg, dtype, *, dense_ff: int = 0, cross: bool = False):
 
 
 def attn_block_forward(p, cfg, x, *, causal=True, window=0, enc_out=None, block_k=512):
-    """Full-sequence block. Returns (x, aux_loss, cache_kv or None)."""
+    """Full-sequence block. Returns (x, aux_loss, cache_kv or None); with
+    held experts (``cfg.experts_held``) aux_loss is the pair (aux_loss,
+    held assignments)."""
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
     if cfg.attention == "mla":
         y, kv = attn.mla_forward(p["attn"], cfg, h, window=window, block_k=block_k,
@@ -87,10 +89,14 @@ def attn_block_forward(p, cfg, x, *, causal=True, window=0, enc_out=None, block_
                                  use_rope=False, block_k=block_k)
     h = rmsnorm(p["ln2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if "moe" in p:
+    if "moe" in p and cfg.experts_held:
+        y, held = moe_held_forward(p["moe"], cfg, h)
+        aux = (aux, held)
+    elif "moe" in p:
         y, aux = moe_forward(p["moe"], cfg, h)
     elif "mlp" in p:
-        y = mlp_forward(p["mlp"], h)
+        with jax.named_scope("ffn"):
+            y = mlp_forward(p["mlp"], h)
     else:
         y = jnp.zeros_like(h)
     x = x + y
@@ -110,10 +116,13 @@ def attn_block_decode(p, cfg, x, cache, pos, *, window=0, enc_out=None):
         h = rmsnorm(p["ln_x"], x, cfg.norm_eps)
         x = x + _cross_decode(p["xattn"], cfg, h, cache["cross_k"], cache["cross_v"])
     h = rmsnorm(p["ln2"], x, cfg.norm_eps)
-    if "moe" in p:
+    if "moe" in p and cfg.experts_held:
+        y, _ = moe_held_forward(p["moe"], cfg, h)
+    elif "moe" in p:
         y, _ = moe_forward(p["moe"], cfg, h)
     elif "mlp" in p:
-        y = mlp_forward(p["mlp"], h)
+        with jax.named_scope("ffn"):
+            y = mlp_forward(p["mlp"], h)
     else:
         y = jnp.zeros_like(h)
     new_cache = dict(cache)
@@ -264,9 +273,12 @@ def _run_encoder(p, cfg, frames, train: bool):
 
 def forward(params, cfg, tokens, prefix_embeds=None, *, train: bool = False,
             window: Optional[int] = None):
-    """Causal full-sequence pass. Returns (hidden (B,S,d), aux dict)."""
+    """Causal full-sequence pass. Returns (hidden (B,S,d), aux dict); with
+    held experts the dict also counts the assignments the pass computed on
+    them, summed over layers (``moe_held``)."""
     win = cfg.sliding_window if window is None else window
     aux_total = jnp.zeros((), jnp.float32)
+    extra = {}
     enc_out = None
     if cfg.is_encoder_decoder:
         pre = prefix_embeds.astype(dtype_of(cfg.compute_dtype))
@@ -314,10 +326,13 @@ def forward(params, cfg, tokens, prefix_embeds=None, *, train: bool = False,
 
         body = _maybe_remat(body, cfg, train)
         x, auxs = jax.lax.scan(body, x, params["layers"])
+        if cfg.experts_held:
+            auxs, held = auxs
+            extra["moe_held"] = jnp.sum(held)
         aux_total = aux_total + jnp.sum(auxs)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return x, {"moe_aux": aux_total}
+    return x, {"moe_aux": aux_total, **extra}
 
 
 # ---------------------------------------------------------------------------

@@ -101,7 +101,7 @@ def make_learner_step(agent, optimizer, lr_schedule, rho_bar: float = 1.0,
     def loss_sync(params, traj, bootstrap):
         # ρ̄ = c̄ = ∞: correction disabled — the paper's on-policy loss,
         # identical graph to the synchronous train step (bitwise lockstep)
-        logits, values, actions, returns = trajectory_forward(
+        logits, values, actions, returns, _ = trajectory_forward(
             params, cfg, hp, traj, bootstrap
         )
         _, rho = _rho(logits, actions, traj.logp)
@@ -112,7 +112,7 @@ def make_learner_step(agent, optimizer, lr_schedule, rho_bar: float = 1.0,
 
     def loss_vtrace(params, traj, bootstrap):
         T, E = traj.action.shape
-        logits, values = trajectory_logits_values(params, cfg, traj)
+        logits, values, _ = trajectory_logits_values(params, cfg, traj)
         actions = traj.action.reshape(T * E)
         logp_now, rho = _rho(logits, actions, traj.logp)
         # V-trace runs on (E, T) matrices; the flattened batch is time-major

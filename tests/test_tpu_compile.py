@@ -14,6 +14,7 @@ one process may load the TPU library, and every test worker imports every
 test file. Keep these tests in this one file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -146,3 +147,35 @@ def test_sharded_learner_step_all_reduces_over_four_chips(topo):
             _on(NamedSharding(mesh, P()), jax.ShapeDtypeStruct((), jnp.int32)),
             _on(repl, params))
     assert "all-reduce" in step.lower(*args).compile().as_text()
+
+
+def test_held_expert_layer_compiles_to_grouped_matmul_kernels(one_chip,
+                                                              monkeypatch):
+    """DeepSeek-V2-Lite's expert layer as one chip of eight holds it (8 of
+    64 experts, width 1408, top-6) on one acting step's 4096 tokens,
+    forward and backward: the held experts' grouped matmuls are the
+    megablox kernels."""
+    from repro.configs import get_config
+    from repro.models import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    cfg = get_config("deepseek-v2-lite").replace(experts_held=8,
+                                                 experts_held_from=0)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: moe.init_moe(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    x = _on(one_chip, jax.ShapeDtypeStruct((8, 512, cfg.d_model),
+                                           jnp.bfloat16))
+
+    def loss(p, x):
+        y, count = moe.moe_held_forward(p, cfg, x)
+        return jnp.sum(y.astype(jnp.float32)), count
+
+    step = jax.jit(jax.grad(loss, has_aux=True))
+    compiled = step.lower(params, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 6  # 3 gmm forward, 3+3 backward
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                         r'op_name="([^"]*)"', text)
+    assert kernels and all("moe.experts" in k and "gmm" in k
+                           for k in kernels), kernels
+    _fits_one_chip(compiled)
