@@ -18,27 +18,33 @@ Two environment regimes:
 The run-loop metrics accounting is shared with ``repro.pipeline`` through
 ``MetricsAccumulator`` so both backends report identical ``RunResult``s.
 
-On the fused path ``ParallelRL.run`` reads each update's metrics one
-update late: the host copy of update k's scalars starts as k is
-dispatched, and they are folded once update k+1 is queued behind it, so
-the device never drains while the host reads. The ``HostEnvPool`` path
-folds each update before its next collect (its staging set is reused).
+On the fused path one dispatch runs up to ``UPDATES_PER_DISPATCH``
+consecutive updates in one program (a ``fori_loop`` over the train step
+with the trip count as an argument, so one executable serves every
+chunk), and hands back their metric scalars packed in one array. The
+host pays a dispatch's cost (the call, one transfer, one fold) once per
+chunk. Each chunk's metrics are read one chunk late: the host copy of
+chunk j's array starts as j is dispatched, and it is folded once chunk
+j+1 is queued behind it, so the device never drains while the host
+reads. The ``HostEnvPool`` path runs one update per dispatch and folds
+it before its next collect (its staging set is reused).
 
 ``ParallelRL.run`` records its host loop into a ``repro.telemetry``
 emitter (track ``driver`` of a per-run hub, kept as ``self.telemetry``):
 ``step.dispatch`` around the train step's call, ``metrics.read`` around
-the host's fold of an update's metrics (the previous update's, on the
+the host's fold of a dispatch's metrics (the previous chunk's, on the
 fused path). ``RunResult.dispatch_s`` / ``readback_s`` are those spans'
-totals, and ``reads_waited`` (hub counter ``reads_waited``) counts the
-deferred folds that still waited on the device. While the JAX profiler
-collects, the spans and a ``StepTraceAnnotation`` per iteration land in
-the profile.
+totals, ``dispatches`` (hub counter ``dispatches``) counts the train
+step's calls, and ``reads_waited`` (hub counter ``reads_waited``) counts
+the deferred folds that still waited on the device. While the JAX
+profiler collects, the spans and a ``StepTraceAnnotation`` per dispatch
+land in the profile.
 """
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +67,16 @@ log = get_logger("framework")
 
 # hub counter of the sync driver's deferred folds that waited on the device
 READS_WAITED = "reads_waited"
+# hub counter of the sync driver's train-step calls
+DISPATCHES = "dispatches"
+# Updates one fused-path dispatch runs at most. A dispatch costs the host
+# the same whatever the update's size: on a TPU v5e about 3.4-3.6 ms in
+# the paper's n_e=32 jobs, against 1.0-1.1 ms of device work an update
+# (PERF.md, section 5). Eight updates give the device 7.9-9.0 ms of work
+# per dispatch, over twice the host's cost; four would leave almost no
+# margin. Jobs whose update outlasts the dispatch lose nothing: their
+# host was hidden already, and the loop costs a compare per update.
+UPDATES_PER_DISPATCH = 8
 # train-step metrics that count work (summed over a run, not averaged in
 # meaning) and the monitoring event each run's total is published under
 COUNTER_PREFIX = "count."
@@ -89,11 +105,25 @@ class RunResult:
     dispatch_s: float = 0.0
     readback_s: float = 0.0
     host_reads: int = 0
-    # deferred folds of the fused sync path whose update had not retired
+    # deferred folds of the fused sync path whose chunk had not retired
     # when the fold began: the host waited on the device, with the next
-    # update already queued behind it (0 on the HostEnvPool path, which
+    # chunk already queued behind it (0 on the HostEnvPool path, which
     # defers nothing, and for the pipeline)
     reads_waited: int = 0
+    # train-step calls of the synchronous driver: one per chunk of up to
+    # UPDATES_PER_DISPATCH updates on the fused path, one per update on the
+    # HostEnvPool path (0 for the pipeline)
+    dispatches: int = 0
+
+
+class PackedMetrics(NamedTuple):
+    """The metric scalars of ``updates`` consecutive updates in one device
+    array: row i holds update i's, in the order of ``keys``; rows from
+    ``updates`` on are unused."""
+
+    rows: jax.Array
+    keys: Tuple[str, ...]
+    updates: int
 
 
 class MetricsAccumulator:
@@ -102,7 +132,10 @@ class MetricsAccumulator:
     Used by both the synchronous ``ParallelRL`` loop and the pipelined
     learner loop so the two backends report identical metric semantics
     (mean-per-iteration metrics, episode counts, timesteps/s over the run's
-    wall-clock).
+    wall-clock). ``update`` takes one update's dict of scalars, or a
+    ``PackedMetrics`` of several updates (the fused sync path): that is
+    brought to the host in one transfer and folded row by row, in the same
+    float arithmetic and order as the rows' dicts would be.
 
     ``lazy=True`` defers the host conversion of device metric scalars: each
     ``update`` only stashes the dict, and the blocking ``float()`` reads
@@ -128,22 +161,33 @@ class MetricsAccumulator:
         self._last: Dict = {}  # most recently *folded* metrics, host-side
         self._t0 = time.perf_counter()
 
-    def update(self, metrics: Dict) -> None:
-        self.iters += 1
+    def update(self, metrics) -> None:
+        self.iters += (metrics.updates if isinstance(metrics, PackedMetrics)
+                       else 1)
         if self.lazy:
             self._pending.append(metrics)
             return
         self._fold(metrics)
 
-    def _fold(self, metrics: Dict) -> None:
-        # each value is read once: float() of a device array is one
-        # device-to-host transfer (one ``np.asarray(jax.Array)`` event in a
-        # profile)
+    def _fold(self, metrics) -> None:
+        # each device array is read once: one device-to-host transfer (one
+        # ``np.asarray(jax.Array)`` event in a profile; a bare np.asarray
+        # takes a path that records none)
+        if isinstance(metrics, PackedMetrics):
+            self.host_reads += 1
+            rows = jax.device_get(metrics.rows)
+            for row in rows[:metrics.updates]:
+                self._add(dict(zip(metrics.keys, map(float, row))))
+            return
         host = {}
         for k, v in metrics.items():
             self.host_reads += isinstance(v, jax.Array)
             host[k] = float(v)
-            self.acc[k] = self.acc.get(k, 0.0) + host[k]
+        self._add(host)
+
+    def _add(self, host: Dict[str, float]) -> None:
+        for k, v in host.items():
+            self.acc[k] = self.acc.get(k, 0.0) + v
         self.episodes += host.get("episodes", 0.0)
         self._last = host
 
@@ -153,9 +197,11 @@ class MetricsAccumulator:
         self._pending.clear()
 
     @staticmethod
-    def _ready(metrics: Dict) -> bool:
+    def _ready(metrics) -> bool:
         # jax.Array.is_ready() == "execution producing this buffer retired";
         # host values (python/numpy scalars) have no is_ready and are ready
+        if isinstance(metrics, PackedMetrics):
+            return metrics.rows.is_ready()
         return all(
             is_ready() if (is_ready := getattr(v, "is_ready", None)) else True
             for v in metrics.values()
@@ -198,6 +244,31 @@ class MetricsAccumulator:
             host_reads=self.host_reads,
             **extra,
         )
+
+
+def loop_train_step(train_step, keys: Tuple[str, ...]):
+    """``train_step`` run ``trips`` times in one program.
+
+    ``looped(state, step0, trips) -> (state, step0 + trips, rows)``: a
+    ``fori_loop`` with a dynamic bound carries the train step's state
+    (its arguments but the step counter), giving update i the counter
+    ``step0 + i``. ``rows`` is f32[UPDATES_PER_DISPATCH, len(keys)]: row i
+    holds update i's metrics in the order of ``keys``, rows from ``trips``
+    on are zero. ``trips`` is an int32 scalar at most UPDATES_PER_DISPATCH.
+    Metric scalars must be exact in f32 (f32, bf16, bool, small ints)."""
+
+    def looped(state, step0, trips):
+        def body(i, carry):
+            state, rows = carry
+            *state, metrics = train_step(*state, step0 + i)
+            row = jnp.stack([metrics[k].astype(jnp.float32) for k in keys])
+            return tuple(state), rows.at[i].set(row)
+
+        rows = jnp.zeros((UPDATES_PER_DISPATCH, len(keys)), jnp.float32)
+        state, rows = jax.lax.fori_loop(0, trips, body, (tuple(state), rows))
+        return state, step0 + trips, rows
+
+    return looped
 
 
 def init_rl_common(env, agent, optimizer: str, lr_schedule, seed: int):
@@ -297,6 +368,10 @@ class ParallelRL:
             self._train_step = jax.jit(
                 agent.make_train_step(env, self.optimizer, self.lr_schedule)
             )
+        # the looped program, built from ``self._train_step`` at its first
+        # use, and its metric keys
+        self._loop = None
+        self._metric_keys: Tuple[str, ...] = ()
         self.total_steps = 0
         self._steps_per_iter = env.n_envs * agent.hp.t_max
 
@@ -308,70 +383,82 @@ class ParallelRL:
         )
         return traj, last_obs
 
+    def _state(self) -> tuple:
+        """The fused train step's arguments but the step counter."""
+        return (self.params, self.opt_state) + (
+            (self.agent_state,) if self._has_agent_state else ()
+        ) + (self.env_state, self.obs, self.key)
+
+    def _set_state(self, state: tuple) -> None:
+        if self._has_agent_state:
+            (self.params, self.opt_state, self.agent_state, self.env_state,
+             self.obs, self.key) = state
+        else:
+            (self.params, self.opt_state, self.env_state, self.obs,
+             self.key) = state
+
+    def _looped(self):
+        """The jitted ``loop_train_step`` of ``self._train_step`` as it is
+        at the first call (a replacement made before then reaches every
+        update). Tracing the step here for its metric keys costs nothing
+        more: the loop's body reuses the trace."""
+        if self._loop is None:
+            step = self._train_step
+            counter = jax.ShapeDtypeStruct((), jnp.int32)
+            metrics = jax.eval_shape(step, *self._state(), counter)[-1]
+            self._metric_keys = tuple(sorted(metrics))
+            self._loop = jax.jit(loop_train_step(step, self._metric_keys))
+        return self._loop
+
     def compiled(self):
-        """The train step as XLA compiled it for this job
-        (``jax.stages.Compiled``; its ``as_text()`` names every instruction
-        with the ``op_name`` scope path it came from). A repeat of the
-        program ``run`` compiled is answered by the compile cache."""
+        """The program a fused-path dispatch runs, as XLA compiled it for
+        this job (``jax.stages.Compiled``; its ``as_text()`` names every
+        instruction with the ``op_name`` scope path it came from). Lowered
+        with the arguments ``run`` passes, so a repeat of the program
+        ``run`` compiled is answered by the compile cache."""
         if self._host:
             raise NotImplementedError(
                 "the HostEnvPool path has no fused train step")
-        state = (self.params, self.opt_state) + (
-            (self.agent_state,) if self._has_agent_state else ()
-        ) + (self.env_state, self.obs, self.key)
-        step = jnp.asarray(0, jnp.int32)
-        return self._train_step.lower(*state, step).compile()
+        step, trips = jnp.asarray(0, jnp.int32), jnp.asarray(1, jnp.int32)
+        return self._looped().lower(self._state(), step, trips).compile()
 
-    def _dispatch(self, step_arr, batch=None):
-        """One call of the jitted step: the fused train step, or on the
-        HostEnvPool path the update on the collected ``batch``."""
+    def _dispatch(self, step_arr, updates: int, batch=None):
+        """One call of a jitted step; returns the next update's step
+        counter and the metrics. Fused path: ``updates`` (at most
+        ``UPDATES_PER_DISPATCH``) consecutive train steps in one program,
+        their metrics a ``PackedMetrics``. ``HostEnvPool`` path: the update
+        on the collected ``batch``, its metrics a dict."""
         if self._host:
             traj, last_obs = batch
             self.params, self.opt_state, metrics = self._update_step(
                 self.params, self.opt_state, traj, last_obs, step_arr
             )
-        elif self._has_agent_state:
-            (
-                self.params,
-                self.opt_state,
-                self.agent_state,
-                self.env_state,
-                self.obs,
-                self.key,
-                metrics,
-            ) = self._train_step(
-                self.params, self.opt_state, self.agent_state,
-                self.env_state, self.obs, self.key, step_arr,
-            )
-        else:
-            (
-                self.params,
-                self.opt_state,
-                self.env_state,
-                self.obs,
-                self.key,
-                metrics,
-            ) = self._train_step(
-                self.params, self.opt_state, self.env_state, self.obs,
-                self.key, step_arr,
-            )
-        return metrics
+            return step_arr + 1, metrics
+        state, step_arr, rows = self._looped()(
+            self._state(), step_arr, jnp.asarray(updates, jnp.int32))
+        self._set_state(state)
+        return step_arr, PackedMetrics(rows, self._metric_keys, updates)
 
     def run(self, iterations: int, log_every: int = 0) -> RunResult:
         """Run `iterations` framework iterations (each = n_e·t_max timesteps).
 
-        On the fused path each update's metrics are folded one update late:
-        after dispatching update k the host starts the copy of k's scalars
-        and folds update k−1's, so at most two updates are in flight and
-        the host only blocks on an update with the next one queued behind
-        it. The last update is folded after the loop, before the clock is
-        read. The ``HostEnvPool`` path folds each update at once: its next
-        collect reuses the staging buffers the update reads.
+        On the fused path the iterations go out in chunks of up to
+        ``UPDATES_PER_DISPATCH`` updates, one dispatch each, and each
+        chunk's metrics are folded one chunk late: after dispatching chunk
+        j the host starts the copy of j's packed metrics and folds chunk
+        j−1's, so at most two chunks are in flight and the host only
+        blocks on a chunk with the next one queued behind it. The last
+        chunk is folded after the loop, before the clock is read. The
+        ``HostEnvPool`` path dispatches one update at a time and folds it
+        at once: its next collect reuses the staging buffers the update
+        reads. ``log_every`` logs at the first dispatch boundary at or past
+        each multiple.
         """
         # fresh telemetry hub per run, kept on self like PipelinedRL's
         self.telemetry = Telemetry()
         spans = self.telemetry.emitter("driver")
         acc = MetricsAccumulator()
+        chunk = 1 if self._host else UPDATES_PER_DISPATCH
 
         def read(metrics, deferred: bool) -> None:
             spans.begin(METRICS_READ)
@@ -382,40 +469,43 @@ class ParallelRL:
 
         step_arr = jnp.asarray(self.total_steps, jnp.int32)
         batch = previous = None
-        for i in range(iterations):
+        done = logged = 0
+        while done < iterations:
+            updates = min(chunk, iterations - done)
             with step_annotation("train",
                                  self.total_steps // self._steps_per_iter):
                 if self._host:
                     batch = self._host_collect()
                 spans.begin(STEP_DISPATCH)
-                metrics = self._dispatch(step_arr, batch)
-                step_arr = step_arr + 1
+                step_arr, metrics = self._dispatch(step_arr, updates, batch)
                 if not self._host:
-                    for v in metrics.values():
-                        if isinstance(v, jax.Array):
-                            v.copy_to_host_async()
+                    metrics.rows.copy_to_host_async()
                 spans.end()
-                self.total_steps += self._steps_per_iter
+                self.telemetry.counter_add(DISPATCHES, 1)
+                done += updates
+                self.total_steps += updates * self._steps_per_iter
                 if self._host:
                     read(metrics, deferred=False)
                 else:
                     if previous is not None:
                         read(previous, deferred=True)
                     previous = metrics
-            if log_every and (i + 1) % log_every == 0:
-                # the folded updates only: no read of the one in flight
+            if log_every and done // log_every > logged:
+                logged = done // log_every
+                # the folded updates only: no read of the chunk in flight
                 log.info(
                     "iter %d steps %d reward_sum %.3f loss %.4f",
-                    i + 1, self.total_steps,
+                    done, self.total_steps,
                     acc.acc.get("reward_sum", 0.0), acc.last("loss"),
                 )
         if previous is not None:
             read(previous, deferred=True)
-        waited = int(self.telemetry.counter(READS_WAITED))
-        result = acc.result(self.total_steps, self._steps_per_iter,
-                            dispatch_s=spans.total(STEP_DISPATCH),
-                            readback_s=spans.total(METRICS_READ),
-                            reads_waited=waited)
+        result = acc.result(
+            self.total_steps, self._steps_per_iter,
+            dispatch_s=spans.total(STEP_DISPATCH),
+            readback_s=spans.total(METRICS_READ),
+            reads_waited=int(self.telemetry.counter(READS_WAITED)),
+            dispatches=int(self.telemetry.counter(DISPATCHES)))
         publish_counters(acc.acc)
         return result
 

@@ -1,20 +1,25 @@
-"""The synchronous driver reads each update's metrics one update late.
+"""The synchronous driver reads each dispatch's metrics one dispatch late.
 
-Pins:
+On the fused path a dispatch runs a chunk of up to
+``UPDATES_PER_DISPATCH`` updates and hands back their metrics packed in
+one array, so these pins are per dispatch (chunk j), not per update:
 
 * ``run(n)`` reports the same ``mean_metrics``, ``episodes`` and
-  ``host_reads`` (``==``) as an eager fold of the same updates — each
-  update folded right after its dispatch — for GridWorld, a small
+  ``host_reads`` (``==``) as an eager fold of the same chunks — each
+  chunk folded right after its dispatch — for GridWorld, a small
   ``FrameStack(AtariLike)`` and the ``agent_state`` agents (DQN,
   LaggedPAAC),
-* on the fused path update k is folded only after update k+1 is
-  dispatched (the last one after the loop), each exactly once and in
-  order, with never more than two updates dispatched and not folded,
-* the ``HostEnvPool`` path folds update k before update k+1's collect,
-  whose staging buffers the update reads,
+* on the fused path chunk j is folded only after chunk j+1 is dispatched
+  (the last one after the loop), each exactly once and in order, with
+  never more than two chunks dispatched and not folded,
+* the ``HostEnvPool`` path (one update per dispatch) folds update k
+  before update k+1's collect, whose staging buffers the update reads,
 * ``RunResult.reads_waited`` counts the deferred folds that found their
-  update still running (at most n) and is 0 on the ``HostEnvPool`` path;
-  the driver's telemetry hub carries the same count.
+  chunk still running (at most the number of dispatches) and is 0 on the
+  ``HostEnvPool`` path; the driver's telemetry hub carries the same
+  count,
+* each dispatch's metrics reach the host in one transfer, started at
+  dispatch and read inside the fold.
 """
 import jax
 import jax.numpy as jnp
@@ -30,7 +35,11 @@ from repro.core.agents import (
     PAACAgent,
     PAACConfig,
 )
-from repro.core.framework import READS_WAITED, MetricsAccumulator
+from repro.core.framework import (
+    READS_WAITED,
+    UPDATES_PER_DISPATCH,
+    MetricsAccumulator,
+)
 from repro.envs import AtariLike, FrameStack, GridWorld, py_bound_spec
 from repro.optim import constant
 
@@ -89,15 +98,22 @@ def jobs():
     return get
 
 
+def _dispatches(n):
+    return -(-n // UPDATES_PER_DISPATCH)
+
+
 def _eager(rl, n):
-    """The driver's loop with each update folded right after its dispatch
+    """The driver's loop with each chunk folded right after its dispatch
     (the fold this module compares the lagged one with)."""
     acc = MetricsAccumulator()
     step_arr = jnp.asarray(rl.total_steps, jnp.int32)
-    for _ in range(n):
-        acc.update(rl._dispatch(step_arr))
-        step_arr = step_arr + 1
-        rl.total_steps += rl._steps_per_iter
+    done = 0
+    while done < n:
+        k = min(UPDATES_PER_DISPATCH, n - done)
+        step_arr, metrics = rl._dispatch(step_arr, k)
+        acc.update(metrics)
+        done += k
+        rl.total_steps += k * rl._steps_per_iter
     return acc.result(rl.total_steps, rl._steps_per_iter)
 
 
@@ -117,56 +133,73 @@ def _lagged_and_eager(rl, n):
     return lagged, eager
 
 
-@pytest.mark.parametrize("n", [1, 2, 7])
+@pytest.mark.parametrize("n", [1, 2, 7, 17])
 @pytest.mark.parametrize("job", ["grid", "atari"])
 def test_lagged_fold_equals_the_eager_fold(jobs, job, n):
     lagged, eager = _lagged_and_eager(jobs(job), n)
     assert lagged.mean_metrics == eager.mean_metrics
     assert lagged.episodes == eager.episodes
-    assert lagged.host_reads == eager.host_reads > 0
+    assert lagged.host_reads == eager.host_reads == _dispatches(n)
     assert lagged.steps == eager.steps
 
 
 @pytest.mark.parametrize("job", ["dqn", "lagged"])
 def test_agent_state_agents_fold_as_the_eager_loop(jobs, job):
-    lagged, eager = _lagged_and_eager(jobs(job), 5)
+    lagged, eager = _lagged_and_eager(jobs(job), 13)
     assert lagged.mean_metrics == eager.mean_metrics
     assert lagged.episodes == eager.episodes
-    assert lagged.host_reads == eager.host_reads > 0
+    assert lagged.host_reads == eager.host_reads == 2
 
 
 def _spy(monkeypatch, rl, events):
-    """Record ("dispatch", k) and ("fold", k) in the order they happen."""
+    """Record ("dispatch", j) and ("fold", j) in the order they happen."""
     dispatch = rl._dispatch
-    dispatched = []  # kept alive, so no two updates share an id()
+    dispatched = []  # kept alive, so no two dispatches share an id()
 
     def spied_dispatch(*args, **kw):
-        metrics = dispatch(*args, **kw)
+        step_arr, metrics = dispatch(*args, **kw)
         dispatched.append(metrics)
         events.append(("dispatch", len(dispatched) - 1))
-        return metrics
+        return step_arr, metrics
 
     update = MetricsAccumulator.update
 
     def spied_update(acc, metrics):
-        k = next(i for i, m in enumerate(dispatched) if m is metrics)
-        events.append(("fold", k))
+        j = next(i for i, m in enumerate(dispatched) if m is metrics)
+        events.append(("fold", j))
         return update(acc, metrics)
 
     monkeypatch.setattr(rl, "_dispatch", spied_dispatch)
     monkeypatch.setattr(MetricsAccumulator, "update", spied_update)
 
 
+def _spy_transfers(monkeypatch, events):
+    """Record ("transfer", None) for each device array brought to the host
+    (JAX's ``np.asarray(jax.Array)`` path)."""
+    from jax._src.array import ArrayImpl
+
+    value = ArrayImpl._value
+
+    def counted(self):
+        events.append(("transfer", None))
+        return value.fget(self)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(counted))
+
+
 def test_update_k_is_folded_after_update_k1_is_dispatched(jobs, monkeypatch):
+    # per dispatch: chunk j is folded after chunk j+1 is dispatched
     rl = jobs("grid")
     events = []
     _spy(monkeypatch, rl, events)
-    n = 6
-    rl.run(n)
+    n = 5 * UPDATES_PER_DISPATCH + 1
+    res = rl.run(n)
+    chunks = _dispatches(n)
+    assert res.dispatches == chunks == 6
     expected = [("dispatch", 0)]
-    for k in range(1, n):
-        expected += [("dispatch", k), ("fold", k - 1)]
-    assert events == expected + [("fold", n - 1)]
+    for j in range(1, chunks):
+        expected += [("dispatch", j), ("fold", j - 1)]
+    assert events == expected + [("fold", chunks - 1)]
     pending = 0
     for kind, _ in events:
         pending += 1 if kind == "dispatch" else -1
@@ -203,19 +236,22 @@ def test_host_env_path_folds_before_the_next_collect(monkeypatch):
 @pytest.mark.parametrize("ready", [True, False])
 def test_reads_waited_counts_folds_that_found_the_update_running(
         jobs, monkeypatch, ready):
+    # per dispatch: a deferred fold counts once for its whole chunk
     rl = jobs("grid")
     monkeypatch.setattr(MetricsAccumulator, "_ready",
                         staticmethod(lambda metrics: ready))
-    n = 5
+    n = 20
     res = rl.run(n)
-    assert res.reads_waited == (0 if ready else n)
+    assert res.dispatches == _dispatches(n) == 3
+    assert res.reads_waited == (0 if ready else res.dispatches)
     assert rl.telemetry.counter(READS_WAITED) == res.reads_waited
 
 
 def test_reads_waited_is_at_most_n_on_the_fused_path(jobs):
-    n = 8
+    # at most the number of dispatches, which is under n
+    n = 20
     res = jobs("grid").run(n)
-    assert 0 <= res.reads_waited <= n
+    assert 0 <= res.reads_waited <= res.dispatches == _dispatches(n)
 
 
 def test_reads_waited_is_zero_on_the_host_env_path(monkeypatch):
@@ -232,29 +268,23 @@ def test_reads_waited_is_zero_on_the_host_env_path(monkeypatch):
 
 
 def test_logging_reads_only_folded_updates(jobs, monkeypatch):
-    from jax._src.array import ArrayImpl
-
     rl = jobs("grid")
     events = []
     _spy(monkeypatch, rl, events)
-    floats = []
-    to_float = ArrayImpl.__float__
-
-    def counted(self):
-        floats.append(self)
-        return to_float(self)
-
-    monkeypatch.setattr(ArrayImpl, "__float__", counted)
-    n = 4
+    transfers = []
+    _spy_transfers(monkeypatch, transfers)
+    n = 20
     res = rl.run(n, log_every=1)
-    # logging every iteration adds no fold and no read of the update in
-    # flight: every device scalar converted is one the folds counted
-    assert [k for kind, k in events if kind == "fold"] == list(range(n))
-    assert len(floats) == res.host_reads == 6 * n
+    # logging at every dispatch adds no fold and no read of the chunk in
+    # flight: every transfer is one the folds counted, one per dispatch
+    assert [j for kind, j in events if kind == "fold"] == [0, 1, 2]
+    assert len(transfers) == res.host_reads == res.dispatches == 3
 
 
 def test_host_copy_starts_at_dispatch_for_every_device_scalar(
         jobs, monkeypatch):
+    # per dispatch: one copy of the packed metrics, started at dispatch,
+    # and one transfer inside the fold that reads it
     from jax._src.array import ArrayImpl
 
     rl = jobs("grid")
@@ -267,11 +297,17 @@ def test_host_copy_starts_at_dispatch_for_every_device_scalar(
         return start_copy(self)
 
     monkeypatch.setattr(ArrayImpl, "copy_to_host_async", spied_copy)
-    n = 3
+    _spy_transfers(monkeypatch, events)
+    n = 20
     res = rl.run(n)
-    # each dispatch is followed by its six copies, before any fold
-    per_update = [("copy", None)] * (res.host_reads // n)
-    expected = [("dispatch", 0)] + per_update
-    for k in range(1, n):
-        expected += [("dispatch", k)] + per_update + [("fold", k - 1)]
-    assert events == expected + [("fold", n - 1)]
+    chunks = res.dispatches
+    assert chunks == _dispatches(n) == res.host_reads
+    # the read may ask for the copy again (jax.device_get does): a no-op
+    # once it has started, so only the copies outside the folds count
+    events = [e for i, e in enumerate(events)
+              if not (e[0] == "copy" and events[i - 1][0] == "fold")]
+    copy, fold = [("copy", None)], [("transfer", None)]
+    expected = [("dispatch", 0)] + copy
+    for j in range(1, chunks):
+        expected += [("dispatch", j)] + copy + [("fold", j - 1)] + fold
+    assert events == expected + [("fold", chunks - 1)] + fold

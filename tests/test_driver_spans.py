@@ -4,15 +4,18 @@ profiler mirroring, and the train step's named scopes.
 Pins:
 
 * ``ParallelRL.run`` records one ``step.dispatch`` and one
-  ``metrics.read`` span per update (the read folds the previous update's
-  scalars after the next dispatch, the last update's after the loop);
-  under ``jax.profiler`` each lands in the profile's host plane, with
-  durations equal to the emitter's totals (``RunResult.dispatch_s`` /
-  ``readback_s``) within 2% — the spans and the device trace share one
-  clock — beside one ``train`` step annotation per update,
+  ``metrics.read`` span per dispatch, a chunk of up to
+  ``UPDATES_PER_DISPATCH`` updates on the fused path (the read folds the
+  previous chunk's packed metrics after the next dispatch, the last
+  chunk's after the loop); under ``jax.profiler`` each lands in the
+  profile's host plane, with durations equal to the emitter's totals
+  (``RunResult.dispatch_s`` / ``readback_s``) within 2% — the spans and
+  the device trace share one clock — beside one ``train`` step
+  annotation per dispatch,
 * with the profiler off no ``TraceAnnotation`` is built, and the new
   ``RunResult`` fields are still filled (``host_reads``: one transfer per
-  device metric scalar, each read once),
+  dispatch on the fused path, per device metric scalar on the
+  ``HostEnvPool`` path, each read once),
 * under the profiler each ``metrics.read`` span holds one
   ``np.asarray(jax.Array)`` event per transfer, and no other span does,
 * ``record()`` spans stay out of the profile, ``begin``/``end`` spans do
@@ -34,6 +37,7 @@ import pytest
 from repro.configs import get_config
 from repro.core import ParallelRL
 from repro.core.agents import PAACAgent, PAACConfig
+from repro.core.framework import UPDATES_PER_DISPATCH
 from repro.envs import AtariLike, FrameStack, GridWorld, py_bound_spec
 from repro.optim import constant
 from repro.telemetry import (
@@ -54,8 +58,13 @@ OLD_CATEGORIES = (
 )
 SCOPES = ("rollout", "policy.forward", "env.step", "learner", "loss_grad",
           "optimizer")
-# the PAAC train step's metric scalars, one transfer each per update
+# the PAAC train step's metric scalars, one transfer each per update on
+# the HostEnvPool path (the fused path packs them: one per dispatch)
 PAAC_METRICS = 6
+
+
+def _dispatches(n):
+    return -(-n // UPDATES_PER_DISPATCH)
 
 
 def _grid_rl(seed=0):
@@ -86,16 +95,21 @@ def test_driver_categories_are_appended():
 def test_driver_spans_land_in_the_profile_on_its_clock(tmp_path):
     rl = _grid_rl()
     rl.run(2)  # compile outside the profile
-    updates = 30
+    # 30 spans of each kind, as many as one update a dispatch made: the
+    # 2% match of the totals needs spans enough to average the profiler's
+    # per-span jitter out; the last chunk is short
+    updates = 30 * UPDATES_PER_DISPATCH - 3
     with jax.profiler.trace(str(tmp_path)):
         res = rl.run(updates)
     events = _host_events(tmp_path)
     dispatch = [e for e in events if e.name == "step.dispatch"]
     read = [e for e in events if e.name == "metrics.read"]
     steps = [e for e in events if e.name == "train"]
-    assert len(dispatch) == len(read) == len(steps) == updates
+    assert len(dispatch) == len(read) == len(steps) == _dispatches(updates)
+    assert len(dispatch) == res.dispatches == 30
+    # each step annotation is numbered by its chunk's first update
     assert sorted(dict(e.stats)["step_num"] for e in steps) == list(
-        range(2, 2 + updates))
+        range(2, 2 + updates, UPDATES_PER_DISPATCH))
     assert sum(e.duration_ns for e in dispatch) * 1e-9 == pytest.approx(
         res.dispatch_s, rel=0.02)
     assert sum(e.duration_ns for e in read) * 1e-9 == pytest.approx(
@@ -110,7 +124,7 @@ def test_driver_spans_land_in_the_profile_on_its_clock(tmp_path):
 def test_each_transfer_is_one_asarray_event_inside_metrics_read(tmp_path):
     rl = _grid_rl()
     rl.run(2)
-    updates = 5
+    updates = 20
     with jax.profiler.trace(str(tmp_path)):
         res = rl.run(updates)
     events = _host_events(tmp_path)
@@ -119,7 +133,7 @@ def test_each_transfer_is_one_asarray_event_inside_metrics_read(tmp_path):
     asarray = [e for e in events if e.name == "np.asarray(jax.Array)"]
     inside = [e for e in asarray if any(
         s <= e.start_ns and e.start_ns + e.duration_ns <= t for s, t in reads)]
-    assert res.host_reads == PAAC_METRICS * updates
+    assert res.host_reads == _dispatches(updates) == 3
     assert len(inside) == res.host_reads
 
 
@@ -135,9 +149,9 @@ def test_no_annotation_is_built_with_the_profiler_off(monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
     monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", RefusedStep)
     rl = _grid_rl()
-    res = rl.run(4)
+    res = rl.run(12)
     assert res.dispatch_s > 0 and res.readback_s > 0
-    assert res.host_reads == PAAC_METRICS * 4
+    assert res.host_reads == res.dispatches == _dispatches(12)
 
 
 def test_record_spans_stay_out_of_the_profile(tmp_path):
@@ -167,6 +181,22 @@ def test_host_reads_count_transfers_not_float_calls():
     assert acc.host_reads == 2  # episodes feeds two sums, read once
     assert acc.episodes == 2.0
     assert acc.result(1, 1).host_reads == 2
+
+
+def test_a_packed_fold_is_one_transfer_and_folds_as_the_dicts():
+    from repro.core.framework import MetricsAccumulator, PackedMetrics
+
+    keys = ("count.x", "episodes", "loss")
+    rows = jnp.asarray([[3.0, 1.0, 0.25], [4.0, 0.0, 1.0 / 3.0],
+                        [9.0, 9.0, 9.0]], jnp.float32)
+    packed, dicts = MetricsAccumulator(), MetricsAccumulator()
+    packed.update(PackedMetrics(rows, keys, 2))
+    for row in rows[:2]:
+        dicts.update(dict(zip(keys, row)))
+    assert packed.host_reads == 1 and dicts.host_reads == 6
+    assert packed.acc == dicts.acc and packed.iters == dicts.iters == 2
+    assert packed.episodes == dicts.episodes == 1.0
+    assert packed.last("loss") == dicts.last("loss")
 
 
 def test_host_env_path_spans_collect_dispatch_and_read():
@@ -234,14 +264,16 @@ def test_train_launcher_traces_the_sync_driver(tmp_path):
     from repro.launch import train
 
     path = tmp_path / "sync_trace.json"
-    train.main(["--arch", "paac_vector", "--iterations", "3", "--n-envs",
+    train.main(["--arch", "paac_vector", "--iterations", "17", "--n-envs",
                 "2", "--t-max", "2", "--ctx", "8", "--trace", str(path)])
     events = json.loads(path.read_text())["traceEvents"]
     tracks = {e["args"]["name"] for e in events
               if e["ph"] == "M" and e["name"] == "thread_name"}
     assert tracks == {"driver"}
     names = [e["name"] for e in events if e["ph"] == "X"]
-    assert names.count("step.dispatch") == names.count("metrics.read") == 3
+    # one span each per dispatch of up to UPDATES_PER_DISPATCH updates
+    assert names.count("step.dispatch") == names.count("metrics.read") == \
+        _dispatches(17)
 
 
 @pytest.mark.parametrize("flag", [["--metrics-jsonl", "hb.jsonl"],

@@ -2,9 +2,10 @@
 
 The TPU compiler is installed wherever libtpu is, and compiles for a
 described ``v5e:2x2`` topology that is not attached. This file compiles
-what the chip runs — ``ParallelRL``'s train step and the pipeline's
-fused-publish learner step for the paper's ``paac_nature`` job (n_e=32,
-t_max=5), the sharded learner step on a 4-chip mesh, and each Pallas
+what the chip runs — ``ParallelRL``'s train step, alone and looped as a
+dispatch runs it, and the pipeline's fused-publish learner step for the
+paper's ``paac_nature`` job (n_e=32, t_max=5), the sharded learner step
+on a 4-chip mesh, and each Pallas
 kernel at the widths of ``repro.kernels.cases`` — so that whatever the
 chip's compiler refuses shows up here. Nothing runs; shapes come from
 ``jax.eval_shape``.
@@ -23,7 +24,7 @@ import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.framework import init_rl_common
+from repro.core.framework import init_rl_common, loop_train_step
 from repro.core.rollout import make_collect_fn
 from repro.distributed.sharding import (
     batch_sharding, replicated_sharding, traj_sharding,
@@ -110,6 +111,21 @@ def test_parallel_rl_train_step_compiles(one_chip):
     args = _on(one_chip, (params, opt_state, state, obs, key,
                           jax.ShapeDtypeStruct((), jnp.int32)))
     _fits_one_chip(step.lower(*args).compile())
+
+
+def test_parallel_rl_multi_update_dispatch_compiles(one_chip):
+    """The program a ``ParallelRL`` dispatch runs on the chip: the train
+    step looped up to ``UPDATES_PER_DISPATCH`` times, the trip count an
+    argument."""
+    env, agent, lr, params, opt_state, state, obs, key, _, _ = _paper_job(32)
+    step = jax.jit(agent.make_train_step(env, make_optimizer("rmsprop"), lr))
+    counter = _on(one_chip, jax.ShapeDtypeStruct((), jnp.int32))
+    carried = _on(one_chip, (params, opt_state, state, obs, key))
+    keys = tuple(sorted(jax.eval_shape(step, *carried, counter)[-1]))
+    loop = jax.jit(loop_train_step(step, keys))
+    compiled = loop.lower(carried, counter, counter).compile()
+    assert re.search(r'op_name="jit\(looped\)/while"', compiled.as_text())
+    _fits_one_chip(compiled)
 
 
 def test_fused_publish_learner_step_compiles(one_chip):
